@@ -28,6 +28,8 @@ def main() -> None:
                          "all suites (.json -> JSON, else Prometheus text)")
     args = ap.parse_args()
     only = args.only.split(",") if args.only else SUITES
+    from repro import use_compile_cache
+    use_compile_cache()
 
     failures = 0
     print("name,us_per_call,derived")

@@ -66,7 +66,11 @@ def save_checkpoint(ckpt_dir: str, step: int, tree: Any, *,
     def write():
         try:
             with obs.span("ckpt.save"):
-                tmp = final + f".tmp{os.getpid()}"
+                # one tmp dir per writer: two async writers of the same
+                # step (a periodic save and the final one) must not
+                # publish each other's half-written directory
+                tmp = (final + f".tmp{os.getpid()}"
+                       f"-{threading.get_ident()}")
                 os.makedirs(tmp, exist_ok=True)
                 np.savez(os.path.join(tmp, "arrays.npz"), **arrays)
                 with open(os.path.join(tmp, "manifest.json"), "w") as f:

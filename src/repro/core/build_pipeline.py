@@ -58,6 +58,8 @@ from .vocab import Vocabulary
 
 _log = obs.get_logger("repro.core.build")
 
+FETCH_BUCKET = 2048   # run rows fetched per bucket (see stream_runs)
+
 
 # ---------------------------------------------------------------------------
 # stage 1: device-side unique-term extraction
@@ -371,8 +373,15 @@ class BuildPipeline:
                     n = int(n_valid)
                 # padded docs (rows >= e): only -1 uniq slots -> masked out
                 with obs.span("build.stage3.spill"):
-                    spiller.add(np.asarray(terms[:n]), np.asarray(docs[:n]),
-                                np.asarray(rows[:n], np.float32))  # stage 3
+                    # fetch a prefix rounded up to whole buckets: a device
+                    # slice of each distinct length is a program of its
+                    # own, compiled anew on nearly every batch
+                    m = min(-(-n // FETCH_BUCKET) * FETCH_BUCKET,
+                            terms.shape[0])
+                    th, dh, vh = jax.device_get(
+                        (terms[:m], docs[:m], rows[:m]))
+                    spiller.add(th[:n].copy(), dh[:n].copy(),
+                                np.array(vh[:n], np.float32))  # stage 3
                 obs.counter("seine_build_docs_total",
                             "docs through build stages 1-3").inc(e - s)
                 obs.counter("seine_build_batches_total",

@@ -246,13 +246,18 @@ def quantize_values(values: np.ndarray, term_offsets: np.ndarray
     bounded by ``scale / 2`` per entry, proportional to the term's own
     magnitude.  Padding postings hold zeros and quantise to zero under
     any scale; empty terms keep the clamp floor (their scale is never
-    applied to a found pair).
+    applied to a found pair).  The elementwise passes run over
+    ``chunk`` postings at a time, so host temporaries stay a fixed size
+    instead of several copies of an index-sized f32 array.
     """
     values = np.asarray(values, np.float32)
     offs = np.asarray(term_offsets, np.int64)
     k, nmax = values.shape[:2]
     vmax = offs.shape[1] - 1
-    amax = np.abs(values).max(axis=(2, 3))                   # (K, Nmax)
+    chunk = 1 << 18
+    amax = np.empty((k, nmax), np.float32)
+    for s in range(0, nmax, chunk):
+        amax[:, s:s + chunk] = np.abs(values[:, s:s + chunk]).max(axis=(2, 3))
     peak = np.zeros((k, vmax), np.float32)
     pos_scale = np.empty((k, nmax), np.float32)
     for i in range(k):
@@ -262,8 +267,11 @@ def quantize_values(values: np.ndarray, term_offsets: np.ndarray
         scale_i = np.maximum(peak[i], 1e-12) / 127.0
         pos_scale[i] = 1.0                                   # pad rows
         pos_scale[i, :term_of.shape[0]] = scale_i[term_of]
-    q = np.clip(np.round(values / pos_scale[..., None, None]),
-                -127, 127).astype(np.int8)
+    q = np.empty(values.shape, np.int8)
+    for s in range(0, nmax, chunk):
+        q[:, s:s + chunk] = np.clip(np.round(
+            values[:, s:s + chunk] / pos_scale[:, s:s + chunk, None, None]),
+            -127, 127)
     return q, (np.maximum(peak, 1e-12) / 127.0).astype(np.float32)
 
 

@@ -53,6 +53,7 @@ import numpy as np
 
 from .. import obs
 from ..core.index import csr_lookup_positions, merge_run_parts
+from ..kernels.csr_lookup.ref import gather_rows
 
 
 @jax.tree_util.register_dataclass
@@ -279,7 +280,7 @@ class PartitionedIndex:
         # otherwise (pre-sub-shard checkpoints, where both are the same)
         range_hi = self.range_hi
 
-        def partial(offsets_k, docs_k, values_k, lo_k, hi_k, k):
+        def partial(offsets_k, docs_k, lo_k, hi_k, k):
             owned = ((shard_of == k) if range_hi is None
                      else (w >= lo_k) & (w <= hi_k)) & valid
             local = (w - lo_k).clip(0)
@@ -287,14 +288,15 @@ class PartitionedIndex:
             found = in_list & owned
             if alive is not None:
                 found = found & alive.at[d].get(mode="clip")
-            vals = values_k.at[pos].get(mode="clip")
-            return vals * found[..., None, None]
+            return pos, found
 
         hi = (self.range_lo if range_hi is None else range_hi)
-        parts = jax.vmap(partial)(
-            self.term_offsets, self.doc_ids, self.values, self.range_lo,
-            hi, jnp.arange(self.n_shards, dtype=self.term_to_shard.dtype))
-        return parts.sum(axis=0)
+        ks = jnp.arange(self.n_shards, dtype=jnp.int32)
+        pos, found = jax.vmap(partial)(
+            self.term_offsets, self.doc_ids, self.range_lo, hi, ks)
+        vals = gather_rows(self.values,
+                           ks.reshape((-1,) + (1,) * (pos.ndim - 1)), pos)
+        return (vals * found[..., None, None]).sum(axis=0)
 
     def qd_matrix(self, query_terms: jnp.ndarray, doc_ids: jnp.ndarray,
                   *, impl: str = None, tile: Optional[int] = None,
@@ -386,6 +388,59 @@ class PartitionedIndex:
                 "sweep tile widths)")
 
 
+# Bytes per host<->device transfer of values.  A transfer changes the
+# layout (the device keeps the posting axis minor, the host row-major) and
+# is staged on the device: building a 10.4 GB index in 256 MiB chunks
+# peaked 4.6 GB above it on a v5e, about a chunk in (24, 128)-padded rows.
+VALUES_CHUNK_BYTES = 1 << 25
+
+
+def device_values(values: np.ndarray,
+                  chunk_bytes: int = VALUES_CHUNK_BYTES):
+    """Host ``(K, N, n_b, n_f)`` posting values -> one device array.
+
+    Arrays past ``chunk_bytes`` move ``chunk_bytes`` of postings at a time
+    into a donated device buffer, so the transfer's staging stays the
+    size of a chunk, never of the index (~10 GB at MQ2007 scale).  The
+    last chunk overlaps the one before it, so every transfer has the
+    same shape and compiles once.
+    """
+    n, rows = _chunk_rows(values, chunk_bytes)
+    if rows >= n:
+        return jnp.asarray(values)
+    put = jax.jit(lambda out, part, i: jax.lax.dynamic_update_slice_in_dim(
+        out, part, i, axis=1), donate_argnums=0)
+    out = jnp.zeros(values.shape, values.dtype)
+    for s in range(0, n, rows):
+        s = min(s, n - rows)
+        out = put(out, values[:, s:s + rows], jnp.int32(s))
+    return out
+
+
+def host_values(values,
+                chunk_bytes: int = VALUES_CHUNK_BYTES) -> np.ndarray:
+    """The way back: device ``(K, N, n_b, n_f)`` values -> host numpy,
+    ``chunk_bytes`` of postings per transfer, so the layout change never
+    stages an index-sized copy on the device either."""
+    n, rows = _chunk_rows(values, chunk_bytes)
+    if rows >= n:
+        return np.asarray(values)
+    take = jax.jit(lambda v, i: jax.lax.dynamic_slice_in_dim(
+        v, i, rows, axis=1))
+    out = np.empty(values.shape, values.dtype)
+    for s in range(0, n, rows):
+        s = min(s, n - rows)
+        out[:, s:s + rows] = np.asarray(take(values, jnp.int32(s)))
+    return out
+
+
+def _chunk_rows(values, chunk_bytes: int):
+    """``(N, postings per chunk_bytes)`` of a (K, N, ...) values array."""
+    n = values.shape[1]
+    row_bytes = values.dtype.itemsize * (values.size // max(n, 1))
+    return n, max(chunk_bytes // max(row_bytes, 1), 1)
+
+
 # ---------------------------------------------------------------------------
 # codec application (core.codec tile-compressed postings)
 # ---------------------------------------------------------------------------
@@ -420,7 +475,7 @@ def _codec_arrays(codec: str, tile: int, doc_ids: np.ndarray,
     if codec == "packed-q8":
         q, scale = codec_mod.quantize_values(np.asarray(values, np.float32),
                                              np.asarray(term_offsets))
-        out.update(values=None, values_q=jnp.asarray(q),
+        out.update(values=None, values_q=device_values(q),
                    value_scale=jnp.asarray(scale))
         raw_bytes += int(np.prod(values.shape)) * 4
         packed_bytes += q.nbytes + scale.nbytes
@@ -459,7 +514,7 @@ def pack_index(pidx: PartitionedIndex, codec: str,
         return pidx
     t = int(tile or POSTING_TILE)
     doc_ids = np.asarray(pidx.doc_ids)
-    values = np.asarray(pidx.values)
+    values = host_values(pidx.values)
     over = _codec_arrays(codec, t, doc_ids, values,
                          np.asarray(pidx.term_offsets))
     over["fences"] = jnp.asarray(build_fences(doc_ids, t))
@@ -689,14 +744,13 @@ def partitioned_from_runs(runs: Sequence, k: int, *, idf: np.ndarray,
     for i in range(k):
         t_lo, t_hi = int(t_first[i]), int(t_last[i]) + 1
         span = t_hi - t_lo
-        loc_offs, loc_docs, loc_vals = merge_run_parts(
-            parts[i], t_lo, t_hi, n_b=n_b, n_f=n_f)
+        loc_offs, loc_docs, _ = merge_run_parts(
+            parts[i], t_lo, t_hi, n_b=n_b, n_f=n_f, out=values[i])
         parts[i] = None                 # free as each shard lands
         n = int(loc_docs.shape[0])
         term_offsets[i, :span + 1] = loc_offs[:span + 1]
         term_offsets[i, span + 1:] = n
         doc_ids[i, :n] = loc_docs
-        values[i, :n] = loc_vals
     # routing: term -> FIRST owning shard.  Sub-shard continuation terms
     # belong (in the table) to the earlier shard; later sub-shards are
     # reached by counting split boundaries <= the candidate doc
@@ -711,7 +765,7 @@ def partitioned_from_runs(runs: Sequence, k: int, *, idf: np.ndarray,
     any_split = bool((split_term >= 0).any())
 
     t = int(codec_tile or POSTING_TILE)
-    over = dict(doc_ids=jnp.asarray(doc_ids), values=jnp.asarray(values),
+    over = dict(doc_ids=jnp.asarray(doc_ids), values=device_values(values),
                 fences=jnp.asarray(build_fences(doc_ids)))
     if codec != "none":
         # pack BEFORE handing arrays to jax; the raw ids exist only

@@ -79,8 +79,6 @@ def sp_decode_attention(mesh: Mesh, axis: str) -> Callable:
     computes stats over its slice, all-gathers the (tiny) stats, and merges
     — one collective of O(B·Hq·hd) instead of moving the KV cache.
     """
-    from jax.experimental.shard_map import shard_map
-
     def local(q, k, v, lengths):
         S_loc = k.shape[1]
         shard = jax.lax.axis_index(axis)
@@ -91,7 +89,7 @@ def sp_decode_attention(mesh: Mesh, axis: str) -> Callable:
                                     jax.lax.all_gather(l, axis),
                                     jax.lax.all_gather(acc, axis))
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(), P(None, axis), P(None, axis), P()),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(), P(None, axis), P(None, axis), P()),
+                       out_specs=P(), check_vma=False)
     return jax.jit(fn)

@@ -1,51 +1,51 @@
-"""csr_lookup — the fused SEINE serving lookup as a Pallas TPU kernel.
+"""csr_lookup — the fused SEINE serving lookup as Pallas TPU kernels.
 
 SEINE's query phase is Eq. 4: M_{q,d}[i] = values[owner(q_i), pos(q_i, d)]
-— pure random access into the term-partitioned CSR.  The old partitioned
-path ran the full-width branchless bisect on EVERY shard for EVERY
-(query-term, doc) pair and materialised K dense partial M matrices in HBM
-before summing them; this kernel is the routed replacement, fusing per
-grid cell:
+— pure random access into the term-partitioned CSR.  The kernels here
+resolve the POSITION half of that expression; the values half is one
+XLA gather in ``ops`` over the compact ``values`` array.  The split is
+what lets the index fill a chip: XLA keeps ``(K, Nmax, n_b, n_f)`` f32
+in a compact layout (the posting axis minor), while a Mosaic operand
+must be (8, 128)-tiled on its last two dims — ``(n_b, n_f) = (20, 9)``
+padded to (24, 128) is 17x the logical bytes, and a per-call relayout
+copy of an index that fills two thirds of HBM cannot exist.  No kernel
+in this module ever sees ``values``.
 
-  * the CSR offset gather  — per-term (shard, lo, hi) ride the SCALAR
-    PREFETCH stream (PrefetchScalarGridSpec, the embed_bag pattern), so
-    block index maps pick the owning shard's fence row before the body
-    runs;
-  * a TWO-LEVEL branchless bisect — level 1 runs over the shard's FENCE
-    row (every T-th doc id, VMEM-resident via the block index map) to
-    find the single T-wide posting tile that can hold the target, level
-    2 DMAs exactly that tile HBM->VMEM and bisects inside it.  VMEM per
-    cell is O(Nmax/T + T) instead of the old O(Nmax) whole-row map, so
-    shards scale to tens of millions of postings instead of the ~1-4M
-    the VMEM-resident row capped them at.  Both levels run the same
-    integer ops as ``core.index._bisect``, and the two-level split is
-    exact (the target position is unique), so results stay bitwise-equal
-    to ``csr_lookup_positions``;
-  * the found-mask select  — the hit's values row is DMA'd from the HBM-
-    resident ``values`` (the O(nnz) bulk never enters VMEM wholesale) and
-    masked to zero for absent / OOV pairs;
-  * the cross-shard merge  — ownership is exclusive per (term, doc-range)
-    (term_to_shard plus the sub-shard split tables are a function of the
-    pair), so the K-partial accumulator degenerates to one exclusive
-    write per (doc, term) output cell: no partials, no sum, no psum.
+:func:`csr_lookup_pallas` — one grid cell per (query term, candidate):
 
-grid = (Q, B): cell (i, j) resolves query term i against candidate j and
-writes the single (1, 1, n_b, n_f) output tile.  Routing comes in two
-ranks: per-term ``(Q,)`` streams (no hot-term sub-shards — the fence row
-block is index-mapped by ``s[i]`` and stays VMEM-resident across the
-B-fastest grid axis), or per-pair ``(Q, B)`` streams (doc-range
-sub-sharded indexes, where the owner is a function of the candidate doc
-too; the fence block index only changes when the owner does, so
-non-split terms still reuse the resident row).
+  * routing — per-(term, hop) lane tables ``(k, lo, hi)`` and the
+    candidate doc ids ride the SCALAR PREFETCH stream.  ``hop`` counts
+    the doc-range sub-shard splits of the term at or below the candidate
+    (``split_term``/``split_doc``, (S,) tables, also prefetched), which
+    is exactly ``ref.route_pairs``; indexes without splits pass one
+    never-matching entry and every pair takes hop 0 (``ref.route_terms``);
+  * a TWO-LEVEL branchless bisect — level 1 over the owner's FENCE row
+    (every T-th doc id; the whole ``(K, F)`` table sits in SMEM, where
+    dynamic scalar reads are native) finds the single T-wide posting
+    tile that can hold the target, level 2 DMAs that tile HBM->SMEM and
+    bisects inside it.  Both levels run the integer ops of
+    ``core.index._bisect`` and the split is exact (the target position
+    is unique), so positions are bitwise-equal to the jnp refs;
+  * the packed codec (``core.codec``) adds a decode between the tile DMA
+    and each probe: the tile's FOR base and word offset come from two
+    more SMEM tables, its bit width from the word-offset difference;
+  * the output is the pair's flat position ``k * Nmax + pos``, or -1
+    when the pair is absent (the sigma=0 exact zero) — ownership is
+    exclusive per (term, doc-range), so one write per cell, no partials.
 
-VMEM per cell: the owner's fence row (ceil(Nmax/T) x 4 B) + one T-wide
-posting tile + one (n_b, n_f) values row.  The tile and values fetches
-are genuinely dynamic HBM->VMEM DMAs (``make_async_copy`` on
-``pltpu.ANY`` refs — the only way to gather by a position computed
-in-kernel); the fence reads at dynamic offsets lower to strided VMEM
-loads.
+:func:`window_rows_pallas` — the first-stage retrieval gather: grid cell
+(lane, window) DMAs a run of whole HBM rows straight into its output
+block; ``ops`` slices the window out and merges it with the shared
+segment scatter.
+
+HBM layout of what the kernels DMA: an int32 id (or packed-word) buffer
+viewed as ``(R, 1, LANES)`` rows.  A DMA may only slice a tiled
+dimension along its tiling, so the (1, 128)-tiled minor pair stays whole
+and every dynamic slice lands on the untiled leading axis — rows.
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -54,18 +54,44 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .ref import bisect_steps
 
+LANES = 128   # words per DMA row of the id / packed-word buffers
 
-def _make_kernel(tile: int, n_fence_iter: int, n_tile_iter: int,
-                 pair_routed: bool):
-    def _kernel(shard_ref, lo_ref, hi_ref, docs_ref, fence_ref, dids_ref,
-                vals_ref, out_ref, tile_buf, buf, sem_t, sem_v):
-        i = pl.program_id(0)                 # query term
-        if pair_routed:                      # owner depends on the doc too
-            j = pl.program_id(1)
-            k, lo0, hi0 = shard_ref[i, j], lo_ref[i, j], hi_ref[i, j]
+
+def window_rows(width: int, align: int) -> int:
+    """Static count of ``LANES``-word rows that cover any ``width``-word
+    window whose flat start is a multiple of ``align``."""
+    g = math.gcd(int(align), LANES)
+    return -(-(LANES - g + int(width)) // LANES)
+
+
+def as_rows(flat: jnp.ndarray, extra_rows: int) -> jnp.ndarray:
+    """(n,) int32 -> (R, 1, LANES) DMA rows, zero-padded by at least
+    ``extra_rows`` whole rows so a ``window_rows``-row DMA starting at
+    any row holding a live word stays in bounds."""
+    n = flat.shape[0]
+    r = -(-n // LANES) + int(extra_rows)
+    return jnp.pad(flat, (0, r * LANES - n)).reshape(r, 1, LANES)
+
+
+def _make_lookup_kernel(*, tile: int, stride: int, n_pos: int, n_rows: int,
+                        n_fence_iter: int, n_tile_iter: int,
+                        packed: bool, mw: int):
+    def _kernel(lk_ref, llo_ref, lhi_ref, qw_ref, st_ref, sd_ref, docs_ref,
+                fence_ref, *rest):
+        if packed:
+            base_ref, woff_ref, rows_ref, out_ref, buf, sem = rest
         else:
-            k, lo0, hi0 = shard_ref[i], lo_ref[i], hi_ref[i]
-        d = docs_ref[0, 0]                   # candidate doc id
+            rows_ref, out_ref, buf, sem = rest
+        i = pl.program_id(0)                 # query term
+        j = pl.program_id(1)                 # candidate
+        d = docs_ref[j]
+        w = qw_ref[i]
+        hop = jnp.int32(0)
+        for s in range(st_ref.shape[0]):     # sub-shard splits at or below d
+            hop = hop + ((st_ref[s] == w) & (sd_ref[s] <= d)).astype(
+                jnp.int32)
+        hop = jnp.minimum(hop, lk_ref.shape[1] - 1)
+        k, lo0, hi0 = lk_ref[i, hop], llo_ref[i, hop], lhi_ref[i, hop]
         n_fence = fence_ref.shape[1]
 
         # level 1 — fence bisect, clamped to the tiles intersecting
@@ -81,7 +107,7 @@ def _make_kernel(tile: int, n_fence_iter: int, n_tile_iter: int,
         def fence_body(_, state):
             flo, fhi = state
             mid = (flo + fhi) // 2
-            v = fence_ref[0, jnp.clip(mid, 0, n_fence - 1)]
+            v = fence_ref[k, jnp.clip(mid, 0, n_fence - 1)]
             go_right = (v < d) & (flo < fhi)
             return (jnp.where(go_right, mid + 1, flo),
                     jnp.where(go_right, fhi, mid))
@@ -95,11 +121,35 @@ def _make_kernel(tile: int, n_fence_iter: int, n_tile_iter: int,
         jt = jnp.clip(jf - 1, 0, n_fence - 1)
         base = jt * tile
 
-        # DMA exactly the winning T-wide posting tile HBM -> VMEM
-        cp = pltpu.make_async_copy(
-            dids_ref.at[pl.ds(k, 1), pl.ds(base, tile)], tile_buf, sem_t)
+        if packed:
+            tb = base_ref[k, jt]
+            wo = woff_ref[k, jt]
+            c = (woff_ref[k, jt + 1] - wo) * 32 // tile   # bits per id
+            mask = (1 << jnp.minimum(c, 16)) - 1
+            start = k * stride + wo
+        else:
+            start = k * stride + base
+        # DMA the rows holding the winning tile HBM -> SMEM
+        row0 = start // LANES
+        off = start - row0 * LANES
+        cp = pltpu.make_async_copy(rows_ref.at[pl.ds(row0, n_rows)], buf,
+                                   sem)
         cp.start()
         cp.wait()
+
+        def word(x):                         # x-th word of the tile
+            f = off + x
+            return buf[f // LANES, 0, f % LANES]
+
+        def probe(p):                        # doc id at position p
+            r = jnp.clip(p - base, 0, tile - 1)
+            if not packed:
+                return word(r)
+            bp = r * c
+            wv = word(jnp.clip(bp // 32, 0, mw - 1))
+            rel = jax.lax.shift_right_logical(
+                wv, jnp.bitwise_and(bp, 31)) & mask
+            return jnp.where(c == 32, wv, tb + rel)
 
         # level 2 — the in-tile bisect over the window [w_lo, w_hi):
         # same ops as core.index._bisect, only bit_length(tile) steps
@@ -109,8 +159,7 @@ def _make_kernel(tile: int, n_fence_iter: int, n_tile_iter: int,
         def tile_body(_, state):
             lo, hi = state
             mid = (lo + hi) // 2
-            v = tile_buf[0, jnp.clip(mid - base, 0, tile - 1)]
-            go_right = (v < d) & (lo < hi)
+            go_right = (probe(mid) < d) & (lo < hi)
             return (jnp.where(go_right, mid + 1, lo),
                     jnp.where(go_right, hi, mid))
 
@@ -118,378 +167,104 @@ def _make_kernel(tile: int, n_fence_iter: int, n_tile_iter: int,
         # the hit value: inside the DMA'd tile, or — when the bisect ran
         # off the window's right edge at a tile boundary still inside
         # [lo, hi) — the next tile's first element, which IS fence jt+1
-        v_tile = tile_buf[0, jnp.clip(pos - base, 0, tile - 1)]
-        v_fence = fence_ref[0, jnp.clip(jt + 1, 0, n_fence - 1)]
-        v_at = jnp.where(pos < w_hi, v_tile, v_fence)
+        v_fence = fence_ref[k, jnp.clip(jt + 1, 0, n_fence - 1)]
+        v_at = jnp.where(pos < w_hi, probe(pos), v_fence)
         found = (pos < hi0) & (v_at == d)
-
-        # fused found-mask select: DMA the hit's values row HBM -> VMEM
-        # and mask — absent pairs emit exact zeros (the sigma=0 semantics)
-        p = jnp.clip(pos, 0, vals_ref.shape[1] - 1)
-        dma = pltpu.make_async_copy(vals_ref.at[k, p], buf, sem_v)
-        dma.start()
-        dma.wait()
-        row = buf[...] * jnp.where(found, 1.0, 0.0).astype(jnp.float32)
-        out_ref[...] = row[None, None]
+        out_ref[i, j] = jnp.where(found, k * n_pos + pos, -1)
 
     return _kernel
 
 
-def _make_retrieve_kernel(tile: int, n_pad: int):
-    def _kernel(k_ref, start_ref, dids_ref, vals_ref, ids_out, vals_out,
-                sem_i, sem_v):
-        lane = pl.program_id(0)
-        w = pl.program_id(1)
-        k = k_ref[lane]
-        # window w of this lane starts `w * tile` postings into the
-        # lane's range; the clamp only engages when every position in
-        # the window is past the shard's real postings (masked to the
-        # overflow bin by merge_windows), so the copied offsets never
-        # shift for a position that is still live
-        s = jnp.clip(start_ref[lane] + w * tile, 0, n_pad - tile)
-        cp_i = pltpu.make_async_copy(
-            dids_ref.at[pl.ds(k, 1), pl.ds(s, tile)], ids_out, sem_i)
-        cp_v = pltpu.make_async_copy(
-            vals_ref.at[pl.ds(k, 1), pl.ds(s, tile)], vals_out, sem_v)
-        cp_i.start()
-        cp_v.start()
-        cp_i.wait()
-        cp_v.wait()
+def csr_lookup_pallas(lane_k: jnp.ndarray, lane_lo: jnp.ndarray,
+                      lane_hi: jnp.ndarray, query_terms: jnp.ndarray,
+                      split_term: jnp.ndarray, split_doc: jnp.ndarray,
+                      doc_targets: jnp.ndarray, fences: jnp.ndarray,
+                      rows: jnp.ndarray, *, tile: int, stride: int,
+                      n_pos: int, packed_meta=None, max_tile_words: int = 0,
+                      interpret: bool = False) -> jnp.ndarray:
+    """Positions of query_terms (Q,) x doc_targets (B,) -> (Q, B) int32.
 
-    return _kernel
-
-
-def retrieve_windows_pallas(lane_shard: jnp.ndarray, lane_start: jnp.ndarray,
-                            doc_ids: jnp.ndarray, values: jnp.ndarray, *,
-                            tile: int, n_win: int,
-                            interpret: bool = False):
-    """Posting-range window gather for first-stage retrieval.
-
-    Where the serving kernel resolves one (term, doc) pair per grid
-    cell, retrieval walks whole posting ranges: lane l (a flattened
-    (query-slot, shard) pair) owns the contiguous posting slice starting
-    at local position ``lane_start[l]`` of shard ``lane_shard[l]``, and
-    grid cell (l, w) DMAs the w-th ``tile``-wide window of doc ids AND
-    values HBM -> VMEM straight into the output blocks — two genuinely
-    dynamic unaligned copies per cell, no compute.  The segment-sum
-    merge (``ref.merge_windows``) happens outside: it is a scatter, which
-    the VPU has no efficient primitive for, while the gather is pure DMA
-    bandwidth the kernel overlaps across grid cells.
-
-    ``doc_ids (K, n_pad)`` / ``values (K, n_pad, n_b, n_f)`` must be
-    padded one tile PAST the fence padding (ops does this) so a window
-    starting at any live position < Nmax stays in bounds.  Returns
-    ``(ids (L, n_win*tile) int32, vals (L, n_win*tile, n_b, n_f) f32)``.
+    ``lane_k/lo/hi`` (Q, H) — the route of each term at each sub-shard
+    hop (``ops.route_hops``); ``split_term``/``split_doc`` (S,) — the
+    sub-shard split tables (one ``-1`` entry when there are none);
+    ``fences`` (K, F) int32.  ``rows`` is the (R, 1, LANES) DMA view of
+    the flat id buffer, where shard k's ids start at word
+    ``k * stride`` (tile-padded, so ``stride = F * tile``) — or, with
+    ``packed_meta = (tile_base (K, F), tile_word_off (K, F+1))``, of the
+    flat packed-word buffer (``stride`` = words per shard row,
+    ``max_tile_words`` the per-tile decode window).  Entry (q, b) is
+    ``k * n_pos + pos`` for a stored pair, -1 otherwise.
     """
-    n_lanes = lane_shard.shape[0]
-    n_pad = doc_ids.shape[1]
-    n_b, n_f = values.shape[2], values.shape[3]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,              # lane_shard, lane_start
-        grid=(n_lanes, n_win),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),      # doc_ids stay in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),      # values stay in HBM
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tile), lambda l, w, k, s: (l, w)),
-            pl.BlockSpec((1, tile, n_b, n_f),
-                         lambda l, w, k, s: (l, w, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.SemaphoreType.DMA(()),
-            pltpu.SemaphoreType.DMA(()),
-        ],
-    )
-    return pl.pallas_call(
-        _make_retrieve_kernel(tile, n_pad),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_lanes, n_win * tile), jnp.int32),
-            jax.ShapeDtypeStruct((n_lanes, n_win * tile, n_b, n_f),
-                                 jnp.float32),
-        ],
-        interpret=interpret,
-    )(lane_shard.astype(jnp.int32), lane_start.astype(jnp.int32),
-      doc_ids, values.astype(jnp.float32))
-
-
-def csr_lookup_pallas(shard: jnp.ndarray, lo: jnp.ndarray, hi: jnp.ndarray,
-                      doc_targets: jnp.ndarray, doc_ids: jnp.ndarray,
-                      fences: jnp.ndarray, values: jnp.ndarray, *,
-                      tile: int, interpret: bool = False) -> jnp.ndarray:
-    """shard/lo/hi (Q,) int32 routed per term (ops.route_terms) or (Q, B)
-    routed per pair (ops.route_pairs, sub-sharded hot terms);
-    doc_targets (B,) int32; doc_ids (K, F*tile) int32 (tile-padded);
-    fences (K, F) int32; values (K, Nmax, n_b, n_f) f32
-    -> M (B, Q, n_b, n_f) f32."""
-    Q = shard.shape[0]
-    B = doc_targets.shape[0]
+    q_n = lane_k.shape[0]
+    b_n = doc_targets.shape[0]
     n_fence = fences.shape[1]
-    n_b, n_f = values.shape[2], values.shape[3]
-    pair_routed = shard.ndim == 2
-    fence_map = ((lambda i, j, s, lo, hi: (s[i, j], 0)) if pair_routed
-                 else (lambda i, j, s, lo, hi: (s[i], 0)))
+    packed = packed_meta is not None
+    n_rows = (window_rows(max_tile_words, 1) if packed
+              else window_rows(tile, tile))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    tables = (fences,) + (tuple(packed_meta) if packed else ())
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,              # shard, lo, hi
-        grid=(Q, B),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, s, lo, hi: (0, j)),
-            pl.BlockSpec((1, n_fence), fence_map),     # owner's fence row
-            pl.BlockSpec(memory_space=pltpu.ANY),      # doc_ids stay in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),      # values stay in HBM
-        ],
-        out_specs=pl.BlockSpec((1, 1, n_b, n_f),
-                               lambda i, j, s, lo, hi: (j, i, 0, 0)),
+        num_scalar_prefetch=7,    # lane k/lo/hi, terms, splits, docs
+        grid=(q_n, b_n),
+        in_specs=[smem] * len(tables) + [
+            pl.BlockSpec(memory_space=pl.ANY)],   # id rows stay in HBM
+        out_specs=smem,
         scratch_shapes=[
-            pltpu.VMEM((1, tile), jnp.int32),
-            pltpu.VMEM((n_b, n_f), jnp.float32),
-            pltpu.SemaphoreType.DMA(()),
+            pltpu.SMEM((n_rows, 1, LANES), jnp.int32),
             pltpu.SemaphoreType.DMA(()),
         ],
     )
     return pl.pallas_call(
-        _make_kernel(tile, bisect_steps(n_fence), bisect_steps(tile),
-                     pair_routed),
+        _make_lookup_kernel(tile=tile, stride=stride, n_pos=n_pos,
+                            n_rows=n_rows,
+                            n_fence_iter=bisect_steps(n_fence),
+                            n_tile_iter=bisect_steps(tile), packed=packed,
+                            mw=max_tile_words),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Q, n_b, n_f), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((q_n, b_n), jnp.int32),
         interpret=interpret,
-    )(shard, lo, hi, doc_targets[None].astype(jnp.int32), fences, doc_ids,
-      values)
+    )(lane_k, lane_lo, lane_hi, query_terms, split_term, split_doc,
+      doc_targets, *tables, rows)
 
 
-# ---------------------------------------------------------------------------
-# packed-codec kernels: decode between the tile DMA and the in-tile bisect
-# ---------------------------------------------------------------------------
+def _window_rows_kernel(row0_ref, rows_ref, out_ref, sem):
+    lane = pl.program_id(0)
+    w = pl.program_id(1)
+    n_rows = out_ref.shape[2]
+    cp = pltpu.make_async_copy(
+        rows_ref.at[pl.ds(row0_ref[lane, w], n_rows)], out_ref.at[0, 0],
+        sem)
+    cp.start()
+    cp.wait()
 
-def _make_packed_kernel(tile: int, n_fence_iter: int, n_tile_iter: int,
-                        pair_routed: bool, mw: int, quantized: bool):
-    """The serving lookup over tile-compressed postings.
 
-    Identical control flow to ``_make_kernel`` — fence bisect, one tile
-    DMA, in-tile bisect, values DMA — except the tile DMA moves
-    ``max_tile_words`` packed int32 words (<= tile/8 of the raw bytes at
-    4-bit width) and every probe decodes its element between the DMA'd
-    buffer and the comparison: one word load + logical shift + mask
-    against the tile's frame-of-reference base.  Width classes divide 32,
-    so no element straddles words and the decode is two scalar VMEM
-    reads — the same op class as the uncompressed probe.  The fence row
-    stays raw, anchoring each tile exactly as before, which is what
-    keeps the two-level split (and therefore the results) bitwise-equal
-    to the uncompressed kernel.  ``quantized`` adds an int8 values row
-    DMA dequantised by the pair's per-term scale (routed outside, one
-    (1, 1) VMEM block per cell).
+def window_rows_pallas(row0: jnp.ndarray, rows: jnp.ndarray, *,
+                       n_rows: int, interpret: bool = False) -> jnp.ndarray:
+    """Posting-window gather for first-stage retrieval.
+
+    Where the lookup resolves one (term, doc) pair per grid cell,
+    retrieval walks whole posting ranges: grid cell (l, w) DMAs rows
+    ``[row0[l, w], row0[l, w] + n_rows)`` of ``rows`` (R, 1, LANES)
+    HBM -> VMEM straight into its output block — one dynamic copy per
+    cell, no compute.  The window slice and the segment-sum merge
+    (``ref.merge_windows``) happen outside: the merge is a scatter,
+    which the VPU has no efficient primitive for, while the gather is
+    pure DMA the kernel overlaps across grid cells.
+    Returns (L, n_win, n_rows, 1, LANES) int32.
     """
-    def _kernel(shard_ref, lo_ref, hi_ref, docs_ref, fence_ref, bits_ref,
-                tbase_ref, woff_ref, scale_ref, packed_ref, vals_ref,
-                out_ref, pw_buf, buf, sem_t, sem_v):
-        i = pl.program_id(0)                 # query term
-        if pair_routed:                      # owner depends on the doc too
-            j = pl.program_id(1)
-            k, lo0, hi0 = shard_ref[i, j], lo_ref[i, j], hi_ref[i, j]
-        else:
-            k, lo0, hi0 = shard_ref[i], lo_ref[i], hi_ref[i]
-        d = docs_ref[0, 0]                   # candidate doc id
-        n_fence = fence_ref.shape[1]
-
-        j_lo = lo0 // tile
-        j_hi = jnp.maximum((hi0 - 1) // tile, j_lo)
-
-        def fence_body(_, state):
-            flo, fhi = state
-            mid = (flo + fhi) // 2
-            v = fence_ref[0, jnp.clip(mid, 0, n_fence - 1)]
-            go_right = (v < d) & (flo < fhi)
-            return (jnp.where(go_right, mid + 1, flo),
-                    jnp.where(go_right, fhi, mid))
-
-        jf, _ = jax.lax.fori_loop(0, n_fence_iter, fence_body,
-                                  (j_lo + 1, j_hi + 1))
-        jt = jnp.clip(jf - 1, 0, n_fence - 1)
-        base = jt * tile
-
-        # the winning tile's codec metadata (VMEM-resident rows, index-
-        # mapped by owner exactly like the fence row) + its packed words
-        c = bits_ref[0, jt]
-        tb = tbase_ref[0, jt]
-        wo = woff_ref[0, jt]
-        mask = (1 << jnp.minimum(c, 16)) - 1
-        cp = pltpu.make_async_copy(
-            packed_ref.at[pl.ds(k, 1), pl.ds(wo, mw)], pw_buf, sem_t)
-        cp.start()
-        cp.wait()
-
-        def dec(p):
-            # decode absolute position p of tile jt from the DMA'd words
-            r = jnp.clip(p - base, 0, tile - 1)
-            bp = r * c
-            wv = pw_buf[0, jnp.clip(bp // 32, 0, mw - 1)]
-            rel = jax.lax.shift_right_logical(
-                wv, jnp.bitwise_and(bp, 31)) & mask
-            return jnp.where(c == 32, wv, tb + rel)
-
-        w_lo = jnp.maximum(base, lo0)
-        w_hi = jnp.minimum(base + tile, hi0)
-
-        def tile_body(_, state):
-            lo, hi = state
-            mid = (lo + hi) // 2
-            go_right = (dec(mid) < d) & (lo < hi)
-            return (jnp.where(go_right, mid + 1, lo),
-                    jnp.where(go_right, hi, mid))
-
-        pos, _ = jax.lax.fori_loop(0, n_tile_iter, tile_body, (w_lo, w_hi))
-        v_fence = fence_ref[0, jnp.clip(jt + 1, 0, n_fence - 1)]
-        v_at = jnp.where(pos < w_hi, dec(pos), v_fence)
-        found = (pos < hi0) & (v_at == d)
-
-        p = jnp.clip(pos, 0, vals_ref.shape[1] - 1)
-        dma = pltpu.make_async_copy(vals_ref.at[k, p], buf, sem_v)
-        dma.start()
-        dma.wait()
-        row = buf[...].astype(jnp.float32)
-        if quantized:
-            row = row * scale_ref[0, 0]
-        row = row * jnp.where(found, 1.0, 0.0).astype(jnp.float32)
-        out_ref[...] = row[None, None]
-
-    return _kernel
-
-
-def csr_lookup_packed_pallas(shard: jnp.ndarray, lo: jnp.ndarray,
-                             hi: jnp.ndarray, doc_targets: jnp.ndarray,
-                             packed, fences: jnp.ndarray,
-                             values: jnp.ndarray, scale, *,
-                             tile: int, max_tile_words: int,
-                             interpret: bool = False) -> jnp.ndarray:
-    """Packed-codec ``csr_lookup_pallas``.  ``packed`` is the
-    ``(packed_words (K, W), tile_bits (K, F), tile_base (K, F),
-    tile_word_off (K, F+1))`` tuple; ``values`` is f32 (codec "packed")
-    or int8 (codec "packed-q8"), in which case ``scale`` carries the
-    per-pair dequant scale shaped (Q, 1) for term routing or (Q, B) for
-    pair routing (gathered outside from the per-term scale table).
-    -> M (B, Q, n_b, n_f) f32."""
-    words, bits, base_t, woff = packed
-    Q = shard.shape[0]
-    B = doc_targets.shape[0]
-    n_fence = fences.shape[1]
-    n_b, n_f = values.shape[2], values.shape[3]
-    pair_routed = shard.ndim == 2
-    row_map = ((lambda i, j, s, lo, hi: (s[i, j], 0)) if pair_routed
-               else (lambda i, j, s, lo, hi: (s[i], 0)))
-    quantized = values.dtype == jnp.int8
-    if scale is None:
-        scale = jnp.ones((Q, 1), jnp.float32)
-    scale_map = ((lambda i, j, s, lo, hi: (i, j)) if scale.shape[1] == B
-                 else (lambda i, j, s, lo, hi: (i, 0)))
+    n_lanes, n_win = row0.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,              # shard, lo, hi
-        grid=(Q, B),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, s, lo, hi: (0, j)),
-            pl.BlockSpec((1, n_fence), row_map),       # owner's fence row
-            pl.BlockSpec((1, n_fence), row_map),       # owner's tile bits
-            pl.BlockSpec((1, n_fence), row_map),       # owner's tile base
-            pl.BlockSpec((1, n_fence + 1), row_map),   # owner's word offs
-            pl.BlockSpec((1, 1), scale_map),           # pair dequant scale
-            pl.BlockSpec(memory_space=pltpu.ANY),      # packed words (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),      # values stay in HBM
-        ],
-        out_specs=pl.BlockSpec((1, 1, n_b, n_f),
-                               lambda i, j, s, lo, hi: (j, i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, max_tile_words), jnp.int32),
-            pltpu.VMEM((n_b, n_f), values.dtype),
-            pltpu.SemaphoreType.DMA(()),
-            pltpu.SemaphoreType.DMA(()),
-        ],
-    )
-    return pl.pallas_call(
-        _make_packed_kernel(tile, bisect_steps(n_fence), bisect_steps(tile),
-                            pair_routed, max_tile_words, quantized),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Q, n_b, n_f), jnp.float32),
-        interpret=interpret,
-    )(shard, lo, hi, doc_targets[None].astype(jnp.int32), fences, bits,
-      base_t, woff, scale.astype(jnp.float32), words, values)
-
-
-def _make_packed_retrieve_kernel(tile: int, mw: int, n_pad: int,
-                                 w_pad: int):
-    def _kernel(k_ref, woff_ref, start_ref, packed_ref, vals_ref,
-                words_out, vals_out, sem_i, sem_v):
-        lane = pl.program_id(0)
-        w = pl.program_id(1)
-        k = k_ref[lane]
-        # per-(lane, window) word offsets ride the scalar prefetch
-        # stream (they are a gather by tile index — cheap outside, a
-        # second DMA hop inside); clamps only engage for windows wholly
-        # past the lane's live span, which the merge masks out
-        wo = jnp.clip(woff_ref[lane, w], 0, w_pad - mw)
-        s = jnp.clip(start_ref[lane] + w * tile, 0, n_pad - tile)
-        cp_i = pltpu.make_async_copy(
-            packed_ref.at[pl.ds(k, 1), pl.ds(wo, mw)], words_out, sem_i)
-        cp_v = pltpu.make_async_copy(
-            vals_ref.at[pl.ds(k, 1), pl.ds(s, tile)], vals_out, sem_v)
-        cp_i.start()
-        cp_v.start()
-        cp_i.wait()
-        cp_v.wait()
-
-    return _kernel
-
-
-def retrieve_windows_packed_pallas(lane_shard: jnp.ndarray,
-                                   lane_woff: jnp.ndarray,
-                                   lane_start: jnp.ndarray,
-                                   packed_words: jnp.ndarray,
-                                   values: jnp.ndarray, *,
-                                   tile: int, max_tile_words: int,
-                                   n_win: int, interpret: bool = False):
-    """Packed-codec ``retrieve_windows_pallas``.
-
-    Lanes are tile-ALIGNED here (ops aligns ``lane_start`` down to the
-    posting-tile boundary — the codec's atomic unit — and masks the
-    leading foreign entries via ``merge_windows(lead=...)``), so window
-    w of lane l is exactly posting tile ``start/tile + w`` and its
-    packed words are one fixed ``max_tile_words`` DMA from
-    ``lane_woff[l, w]``.  Ids come back as RAW packed words — the
-    bit-unpack is a vector gather per element, which ops runs outside
-    the kernel in jnp for the same reason the merge scatter lives
-    outside; values DMA at their storage dtype (f32 or int8, dequant
-    outside).  Returns ``(words (L, n_win*max_tile_words) int32,
-    vals (L, n_win*tile, n_b, n_f) values.dtype)``.
-    """
-    n_lanes = lane_shard.shape[0]
-    n_pad = values.shape[1]
-    w_pad = packed_words.shape[1]
-    n_b, n_f = values.shape[2], values.shape[3]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,              # lane_shard, lane_woff, start
+        num_scalar_prefetch=1,
         grid=(n_lanes, n_win),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),      # packed words (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),      # values stay in HBM
-        ],
-        out_specs=[
-            pl.BlockSpec((1, max_tile_words), lambda l, w, k, o, s: (l, w)),
-            pl.BlockSpec((1, tile, n_b, n_f),
-                         lambda l, w, k, o, s: (l, w, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.SemaphoreType.DMA(()),
-            pltpu.SemaphoreType.DMA(()),
-        ],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, 1, n_rows, 1, LANES),
+                               lambda l, w, r: (l, w, 0, 0, 0)),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
     )
     return pl.pallas_call(
-        _make_packed_retrieve_kernel(tile, max_tile_words, n_pad, w_pad),
+        _window_rows_kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_lanes, n_win * max_tile_words),
-                                 jnp.int32),
-            jax.ShapeDtypeStruct((n_lanes, n_win * tile, n_b, n_f),
-                                 values.dtype),
-        ],
+        out_shape=jax.ShapeDtypeStruct((n_lanes, n_win, n_rows, 1, LANES),
+                                       jnp.int32),
         interpret=interpret,
-    )(lane_shard.astype(jnp.int32), lane_woff.astype(jnp.int32),
-      lane_start.astype(jnp.int32), packed_words, values)
+    )(row0.astype(jnp.int32), rows)

@@ -1,23 +1,26 @@
-"""jit'd public wrapper for the fused csr_lookup serving kernel.
+"""jit'd public wrappers for the fused csr_lookup serving kernels.
 
 Backend dispatch differs from the sibling kernels on purpose: this op IS
-the serving hot path, latency-gated by scripts/ci.sh bench, so on CPU it
-lowers to :func:`~.ref.csr_lookup_ref` — the routed-gather jnp expression
-of the SAME fused dataflow (one bisect per (term, doc) pair against the
-owning shard, no K partials), bitwise-identical to the kernel — instead
-of the Pallas interpreter, which emulates the grid cell-by-cell and is a
-correctness tool, not a fast path.  ``interpret=True`` forces the
-interpreter (the oracle-parity sweep in tests/test_kernels.py);
-``interpret=False`` forces the compiled TPU kernel.
+the serving hot path, so on CPU it lowers to :func:`~.ref.csr_lookup_ref`
+— the routed-gather jnp expression of the SAME fused dataflow (one
+bisect per (term, doc) pair against the owning shard, no K partials),
+bitwise-identical to the kernel — instead of the Pallas interpreter,
+which emulates the grid cell-by-cell and is a correctness tool, not a
+fast path.  ``interpret=True`` forces the interpreter (the oracle-parity
+sweeps in the tests); ``interpret=False`` forces the compiled TPU
+kernel.
 
-The kernel path runs the two-level tiled bisect: ``fences`` (every
-``tile``-th doc id, built at index-build time by
-``core.index.build_fences``) are bisected in VMEM first, then only the
-winning ``tile``-wide posting slice is DMA'd HBM->VMEM.  ``doc_ids`` is
-padded here to a whole number of tiles so the slice DMA is always in
-bounds; fences are rebuilt on the fly whenever the provided array does
-not match the requested ``tile`` (e.g. the parity sweep overriding the
-build-time default).
+The kernel path splits the lookup in two.  The Pallas kernel resolves
+positions with the two-level tiled bisect: ``fences`` (every ``tile``-th
+doc id, built at index-build time by ``core.index.build_fences``) are
+bisected in SMEM first, then only the winning ``tile``-wide posting
+slice is DMA'd.  One XLA gather then reads the value rows at those
+positions from the compact ``values`` array, which no kernel takes as an
+operand (see ``kernel.py`` for why).  ``doc_ids`` is padded here to a
+whole number of tiles per shard and viewed as DMA rows; fences are
+rebuilt on the fly whenever the provided array does not match the
+requested ``tile`` (e.g. the parity sweep overriding the build-time
+default).
 """
 from __future__ import annotations
 
@@ -27,13 +30,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kernel import (csr_lookup_packed_pallas, csr_lookup_pallas,
-                     retrieve_windows_packed_pallas, retrieve_windows_pallas)
+from .kernel import (LANES, as_rows, csr_lookup_pallas, window_rows,
+                     window_rows_pallas)
 from .ref import (bisect_steps, cached_tile_lookup, csr_lookup_packed_ref,
-                  csr_lookup_ref, lookup_pairs_ref, merge_windows,
-                  packed_bisect, retrieve_block_packed_ref,
-                  retrieve_block_ref, retrieve_lanes, route_pairs,
-                  route_terms, _alive_at, _lane_scale)
+                  csr_lookup_ref, gather_rows, lookup_pairs_ref,
+                  merge_windows, packed_bisect, retrieve_block_packed_ref,
+                  retrieve_block_ref, retrieve_lanes, route_hops,
+                  route_pairs, route_terms, _alive_at, _lane_scale)
 
 
 def _check_packed_args(codec, packed, fences, values, tile, t):
@@ -61,6 +64,61 @@ def _check_packed_args(codec, packed, fences, values, tile, t):
             "at their build-time codec tile")
     if codec == "packed-q8" and values.dtype != jnp.int8:
         raise ValueError("codec 'packed-q8' expects int8 values")
+
+
+def _id_rows(doc_ids, t):
+    """``doc_ids (K, N)`` -> ``(flat, rows)``: the ids padded to a whole
+    number of tiles per shard (int32 max, so fences stay monotone) and
+    flattened — shard k's ids start at ``k * F * t`` — plus the DMA-row
+    view of that buffer the kernels read."""
+    from ...core.index import fence_count
+
+    n = doc_ids.shape[1]
+    f = fence_count(n, t)
+    flat = jnp.pad(doc_ids, ((0, 0), (0, f * t - n)),
+                   constant_values=np.iinfo(np.int32).max).reshape(-1)
+    return flat, as_rows(flat, window_rows(t, t))
+
+
+def _word_rows(packed, mw):
+    """DMA-row view of the flat packed-word buffer (shard k's words
+    start at ``k * W``)."""
+    return as_rows(packed[0].reshape(-1), window_rows(mw, 1))
+
+
+def _lookup_found(term_offsets, term_to_shard, range_lo, split_term,
+                  split_doc, query_terms, doc_targets, fences, rows,
+                  values, value_scale, *, tile, stride, packed_meta=None,
+                  max_tile_words=0, interpret=False):
+    """Kernel-path lookup: route, resolve positions in the Pallas kernel,
+    gather the found rows from ``values`` -> M (B, Q, n_b, n_f).
+
+    The gather and the masking select are the jnp refs' expressions
+    (``values[k, pos]`` where found, exact zeros elsewhere; q8 dequant
+    by the pair's per-term scale), so M is bitwise-equal to them."""
+    hops = 1 if split_term is None else term_offsets.shape[0]
+    k, lo, hi = route_hops(query_terms, term_offsets, term_to_shard,
+                           range_lo, hops)
+    if split_term is None:                 # one entry that never matches
+        split_term = jnp.full((1,), -1, jnp.int32)
+        split_doc = jnp.zeros((1,), jnp.int32)
+    n = values.shape[1]
+    pos = csr_lookup_pallas(
+        k, lo, hi, query_terms.clip(0).astype(jnp.int32),
+        split_term.astype(jnp.int32), split_doc.astype(jnp.int32),
+        doc_targets.astype(jnp.int32), fences, rows, tile=tile,
+        stride=stride, n_pos=n, packed_meta=packed_meta,
+        max_tile_words=max_tile_words, interpret=interpret)
+    flat = pos.T                                          # (B, Q)
+    found = (flat >= 0)[..., None, None]
+    f = jnp.maximum(flat, 0)
+    kk = f // n
+    vals = gather_rows(values, kk, f - kk * n)
+    if value_scale is not None:
+        sc = _lane_scale(value_scale, range_lo, kk, query_terms[None, :])
+        return jnp.where(found, vals.astype(jnp.float32)
+                         * sc[..., None, None], 0.0)
+    return jnp.where(found, vals, 0.0)
 
 
 @partial(jax.jit,
@@ -95,7 +153,7 @@ def csr_lookup(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
     ``codec="packed"``/``"packed-q8"`` serves tile-compressed postings
     (``core.codec``): ``doc_ids`` is None, ``packed`` carries the
     ``(packed_words, tile_bits, tile_base, tile_word_off)`` tuple (plus
-    ``max_tile_words``, the static per-tile DMA window), and for q8
+    ``max_tile_words``, the static per-tile decode window), and for q8
     ``values`` is int8 with ``value_scale (K, Vmax)`` per-term dequant
     scales.  Ids decode losslessly, so packed results stay bitwise-equal
     to the uncompressed oracle; ``tile`` must equal the build-time codec
@@ -106,8 +164,8 @@ def csr_lookup(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
 
     ``alive`` (n_docs,) bool tombstones deleted docs: their pairs
     resolve to the same exact zeros as absent pairs.  On the CPU refs it
-    folds into the found mask; on the kernel paths the kernel's output
-    rows are masked per candidate doc — mathematically identical, since
+    folds into the found mask; on the kernel paths the output rows are
+    masked per candidate doc — mathematically identical, since
     not-found rows are already exact zeros and the mask is per doc.
     """
     from ...core.index import POSTING_TILE, build_fences, fence_count
@@ -121,55 +179,28 @@ def csr_lookup(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
                 term_to_shard, range_lo, query_terms, doc_targets,
                 split_term, split_doc, tile=t, spans=tuple(codec_spans),
                 alive=alive)
-        if split_term is None:
-            k, lo, hi = route_terms(query_terms, term_offsets,
-                                    term_to_shard, range_lo)
-            scale_w = query_terms
-        else:
-            shape = (query_terms.shape[0], doc_targets.shape[0])  # (Q, B)
-            scale_w = jnp.broadcast_to(query_terms[:, None], shape)
-            k, lo, hi = route_pairs(
-                scale_w, jnp.broadcast_to(doc_targets[None], shape),
-                term_offsets, term_to_shard, range_lo, split_term,
-                split_doc)
-        scale = None
-        if value_scale is not None:
-            scale = _lane_scale(value_scale, range_lo, k, scale_w)
-            if scale.ndim == 1:
-                scale = scale[:, None]                   # (Q, 1)
-        out = csr_lookup_packed_pallas(
-            k.astype(jnp.int32), lo.astype(jnp.int32), hi.astype(jnp.int32),
-            doc_targets.astype(jnp.int32), packed, fences, values, scale,
-            tile=t, max_tile_words=int(max_tile_words),
+        mw = int(max_tile_words)
+        out = _lookup_found(
+            term_offsets, term_to_shard, range_lo, split_term, split_doc,
+            query_terms, doc_targets, fences, _word_rows(packed, mw),
+            values, value_scale, tile=t, stride=packed[0].shape[1],
+            packed_meta=(packed[2], packed[3]), max_tile_words=mw,
             interpret=bool(interpret))
         return _mask_dead_rows(out, alive, doc_targets)
     if interpret is None and jax.default_backend() != "tpu":
         return csr_lookup_ref(term_offsets, doc_ids, values, term_to_shard,
                               range_lo, query_terms, doc_targets,
                               split_term, split_doc, alive=alive)
-    if split_term is None:
-        k, lo, hi = route_terms(query_terms, term_offsets, term_to_shard,
-                                range_lo)
-    else:
-        shape = (query_terms.shape[0], doc_targets.shape[0])     # (Q, B)
-        k, lo, hi = route_pairs(
-            jnp.broadcast_to(query_terms[:, None], shape),
-            jnp.broadcast_to(doc_targets[None], shape),
-            term_offsets, term_to_shard, range_lo, split_term, split_doc)
-    n = doc_ids.shape[1]
-    n_fence = fence_count(n, t)
-    pad = n_fence * t - n
-    if pad:
-        doc_ids = jnp.pad(doc_ids, ((0, 0), (0, pad)),
-                          constant_values=np.iinfo(np.int32).max)
+    n_fence = fence_count(doc_ids.shape[1], t)
     # stored fences are spaced at the build-time POSTING_TILE — rebuild
     # whenever the requested tile disagrees (the parity sweep's override)
     if fences is None or t != POSTING_TILE or fences.shape[1] != n_fence:
-        fences = build_fences(doc_ids, t)    # already tile-padded: exact
-    out = csr_lookup_pallas(
-        k.astype(jnp.int32), lo.astype(jnp.int32), hi.astype(jnp.int32),
-        doc_targets.astype(jnp.int32), doc_ids, fences,
-        values.astype(jnp.float32), tile=t, interpret=bool(interpret))
+        fences = build_fences(doc_ids, t)
+    _, rows = _id_rows(doc_ids, t)
+    out = _lookup_found(
+        term_offsets, term_to_shard, range_lo, split_term, split_doc,
+        query_terms, doc_targets, fences, rows, values, None, tile=t,
+        stride=n_fence * t, interpret=bool(interpret))
     return _mask_dead_rows(out, alive, doc_targets)
 
 
@@ -184,127 +215,125 @@ def _mask_dead_rows(out, alive, doc_targets):
                      out, 0.0)
 
 
-def _pad_for_windows(doc_ids, values, t):
-    """Pad postings one tile PAST the fence padding so a window DMA
-    starting at any live local position <= Nmax stays in bounds."""
-    from ...core.index import fence_count
+def _fetch_windows(starts, rows, *, width: int, align: int, interpret):
+    """``starts (..., n_win)`` flat word offsets (multiples of ``align``)
+    -> ``(..., n_win, width)`` words, through the window-gather kernel:
+    it DMAs the covering rows, the window is sliced out here."""
+    n_rows = window_rows(width, align)
+    s = starts.reshape(-1, starts.shape[-1]).astype(jnp.int32)
+    row0 = s // LANES
+    got = window_rows_pallas(row0, rows, n_rows=n_rows, interpret=interpret)
+    words = got.reshape(s.shape + (n_rows * LANES,))
+    idx = (s - row0 * LANES)[..., None] + jnp.arange(width, dtype=jnp.int32)
+    return jnp.take_along_axis(words, idx, axis=-1).reshape(
+        starts.shape + (width,))
 
-    n = doc_ids.shape[1]
-    pad = fence_count(n, t) * t + t - n
-    dids_p = jnp.pad(doc_ids, ((0, 0), (0, pad)),
-                     constant_values=np.iinfo(np.int32).max)
-    vals_p = jnp.pad(values.astype(jnp.float32),
-                     ((0, 0), (0, pad)) + ((0, 0),) * (values.ndim - 2))
-    return dids_p, vals_p
+
+def _merge_window_block(ids, values, scale, ks, j0, s_lo, s_hi, blo,
+                        block, t, alive):
+    """Shared tail of both kernel-path doc blocks: lanes (Q, K) walk
+    ``n_win`` tile-aligned windows from tile ``j0``; gather their value
+    rows from the compact ``values`` (XLA — no kernel takes ``values``),
+    dequantise by the lane ``scale`` when given, and segment-merge.  The
+    windows start at the tile boundary below ``s_lo``, so the first
+    ``lead`` entries belong to docs below the block and ``merge_windows``
+    routes them to the overflow bin with the tail."""
+    q_n, k_n = ks.shape
+    w = ids.shape[2] * ids.shape[3]
+    pos = (j0 * t)[..., None] + jnp.arange(w, dtype=jnp.int32)
+    val_win = gather_rows(values, ks[..., None], pos)
+    if scale is not None:
+        val_win = val_win.astype(jnp.float32) * scale[..., None, None, None]
+    return merge_windows(ids.reshape(q_n, k_n, w), val_win, s_hi - s_lo,
+                         blo, block, lead=s_lo - j0 * t, alive=alive)
 
 
-def _retrieve_block_windows(term_offsets, dids_p, vals_p, term_to_shard,
+def _retrieve_block_windows(term_offsets, ids, values, term_to_shard,
                             range_lo, range_hi, query_terms, blo, block,
                             t, interpret, alive=None):
-    """Kernel-path doc block: locate lane windows in jnp, gather via the
-    Pallas window kernel, merge with the shared segment scatter.
+    """Kernel-path doc block: locate lane windows in jnp, gather their
+    ids via the Pallas window kernel, merge with the shared scatter.
 
     The jnp part — lane ranges plus two range bisects per lane, the same
     branchless ``core.index._bisect`` the lookup runs, O(log Nmax) each —
-    stays outside the kernel; the kernel only streams the located
-    windows HBM -> VMEM.  ``dids_p``/``vals_p`` come pre-padded from
-    :func:`_pad_for_windows` (hoisted out of the top-k block loop so the
-    O(nnz) values pad is paid once per retrieve, not per block).
+    stays outside the kernel; the kernel only streams the located id
+    windows.  ``ids`` is :func:`_id_rows`'s ``(flat, rows)`` pair,
+    hoisted out of the top-k block loop so the tile pad is paid once
+    per retrieve, not per block.
     """
     from ...core.index import _bisect
 
-    k_n, n_pad = dids_p.shape
+    flat, rows = ids
+    k_n = term_offsets.shape[0]
+    stride = flat.shape[0] // k_n
     q_n = query_terms.shape[0]
-    flat = dids_p.reshape(k_n * n_pad)
     lo_f, hi_f = retrieve_lanes(query_terms, term_offsets, term_to_shard,
-                                range_lo, range_hi, n_pad)
-    steps = bisect_steps(n_pad)
+                                range_lo, range_hi, stride)
+    steps = bisect_steps(stride)
     s_lo = _bisect(flat, lo_f, hi_f, jnp.broadcast_to(blo, lo_f.shape),
                    n_iter=steps)
     s_hi = _bisect(flat, lo_f, hi_f,
                    jnp.broadcast_to(blo + block, lo_f.shape), n_iter=steps)
-    base = jnp.arange(k_n, dtype=jnp.int32)[None, :] * n_pad
-    lane_start = (s_lo - base).reshape(-1)
-    lane_k = jnp.broadcast_to(jnp.arange(k_n, dtype=jnp.int32)[None, :],
-                              (q_n, k_n)).reshape(-1)
-    n_win = -(-block // t)
-    ids_w, vals_w = retrieve_windows_pallas(
-        lane_k, lane_start, dids_p, vals_p, tile=t, n_win=n_win,
-        interpret=interpret)
-    w = n_win * t
-    doc_win = ids_w.reshape(q_n, k_n, w)
-    val_win = vals_w.reshape((q_n, k_n, w) + vals_p.shape[2:])
-    return merge_windows(doc_win, val_win, s_hi - s_lo, blo, block,
-                         alive=alive)
+    ks = jnp.broadcast_to(jnp.arange(k_n, dtype=jnp.int32)[None, :],
+                          (q_n, k_n))
+    base = ks * stride
+    j0 = (s_lo - base) // t
+    n_win = -(-block // t) + 1                            # +1: lead spill
+    tiles = jnp.clip(j0[..., None] + jnp.arange(n_win), 0, stride // t - 1)
+    doc_win = _fetch_windows(base[..., None] + tiles * t, rows, width=t,
+                             align=t, interpret=interpret)
+    return _merge_window_block(doc_win, values, None, ks, j0, s_lo - base,
+                               s_hi - base, blo, block, t, alive)
 
 
-def _pad_vals_for_windows(values, t):
-    """Values-only window padding at the storage dtype (f32 or int8) —
-    the packed path has no raw doc-id row to pad; ids travel as packed
-    words whose own rows are already padded by one DMA window."""
-    from ...core.index import fence_count
-
-    n = values.shape[1]
-    pad = fence_count(n, t) * t + t - n
-    return jnp.pad(values, ((0, 0), (0, pad)) + ((0, 0),) * (values.ndim - 2))
-
-
-def _retrieve_block_windows_packed(term_offsets, packed, fences, vals_p,
-                                   value_scale, term_to_shard, range_lo,
-                                   range_hi, query_terms, blo, block,
-                                   t, mw, interpret, alive=None):
+def _retrieve_block_windows_packed(term_offsets, packed, word_rows, fences,
+                                   values, value_scale, term_to_shard,
+                                   range_lo, range_hi, query_terms, blo,
+                                   block, t, mw, interpret, alive=None):
     """Packed-codec kernel-path doc block.
 
-    Lane windows must start on posting-tile boundaries — the tile is the
+    Lane windows start on posting-tile boundaries — the tile is the
     codec's atomic decode unit — so each lane's window run is aligned
     DOWN from its first live position (one extra window absorbs the
-    spill) and ``merge_windows(lead=...)`` masks the leading foreign
-    entries.  The two range bisects run as packed two-level bisects; the
-    kernel DMAs fixed ``max_tile_words`` packed-word windows plus the
-    value windows at their storage dtype, and the bit-unpack of the id
-    windows happens OUT HERE in jnp — it is a vector gather per element,
-    the same reason the merge scatter never entered the kernel.
+    spill).  The two range bisects run as packed two-level bisects; the
+    kernel DMAs each window's ``max_tile_words`` packed words, and the
+    bit-unpack happens OUT HERE in jnp — it is a vector gather per
+    element, the same reason the merge scatter never entered the kernel.
     """
     words, bits, base_t, woff = packed
-    k_n, n_pad = vals_p.shape[0], vals_p.shape[1]
+    k_n, n = values.shape[0], values.shape[1]
     f = bits.shape[1]
     q_n = query_terms.shape[0]
     lo_f, hi_f = retrieve_lanes(query_terms, term_offsets, term_to_shard,
-                                range_lo, range_hi, n_pad)
+                                range_lo, range_hi, n)
     ks = jnp.broadcast_to(jnp.arange(k_n, dtype=jnp.int32)[None, :],
                           lo_f.shape)
-    base = ks * n_pad
+    base = ks * n
     lo_l, hi_l = lo_f - base, hi_f - base
     s_lo = packed_bisect(packed, fences, ks, lo_l, hi_l,
                          jnp.broadcast_to(blo, lo_l.shape), tile=t)
     s_hi = packed_bisect(packed, fences, ks, lo_l, hi_l,
                          jnp.broadcast_to(blo + block, lo_l.shape), tile=t)
     j0 = s_lo // t
-    lead = s_lo - j0 * t                                  # (Q, K)
     n_win = -(-block // t) + 1                            # +1: lead spill
     jwin = jnp.clip(j0[..., None] + jnp.arange(n_win), 0, f - 1)
-    lane_woff = woff[ks[..., None], jwin].reshape(-1, n_win)
-    words_w, vals_w = retrieve_windows_packed_pallas(
-        ks.reshape(-1), lane_woff, (j0 * t).reshape(-1), words, vals_p,
-        tile=t, max_tile_words=mw, n_win=n_win, interpret=interpret)
+    kw = ks[..., None]
+    ww = _fetch_windows(kw * words.shape[1] + woff[kw, jwin], word_rows,
+                        width=mw, align=1, interpret=interpret)
     # decode the id windows: tile metadata gathered per (lane, window),
-    # words gathered per element from the DMA'd fixed-size blocks
-    ww = words_w.reshape(q_n, k_n, n_win, mw)
-    c = bits[ks[..., None], jwin]                         # (Q, K, n_win)
-    tb = base_t[ks[..., None], jwin]
+    # words gathered per element from the DMA'd windows
+    c = bits[kw, jwin]                                    # (Q, K, n_win)
+    tb = base_t[kw, jwin]
     bp = jnp.arange(t)[None, None, None, :] * c[..., None]
     wv = jnp.take_along_axis(ww, jnp.clip(bp // 32, 0, mw - 1), axis=-1)
     rel = jax.lax.shift_right_logical(wv, jnp.bitwise_and(bp, 31)) \
         & ((1 << jnp.minimum(c, 16)) - 1)[..., None]
     ids = jnp.where(c[..., None] == 32, wv, tb[..., None] + rel)
-    w = n_win * t
-    doc_win = ids.reshape(q_n, k_n, w)
-    val_win = vals_w.reshape((q_n, k_n, w) + vals_p.shape[2:])
+    scale = None
     if value_scale is not None:
         scale = _lane_scale(value_scale, range_lo, ks, query_terms[:, None])
-        val_win = val_win.astype(jnp.float32) * scale[..., None, None, None]
-    return merge_windows(doc_win, val_win, s_hi - s_lo, blo, block,
-                         lead=lead, alive=alive)
+    return _merge_window_block(ids, values, scale, ks, j0, s_lo, s_hi, blo,
+                               block, t, alive)
 
 
 def _retrieve_dispatch(impl):
@@ -363,20 +392,19 @@ def csr_retrieve_block(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
                 term_offsets, packed, fences, values, value_scale,
                 term_to_shard, range_lo, range_hi, query_terms, blo,
                 block, tile=t, spans=tuple(codec_spans), alive=alive)
-        vals_p = _pad_vals_for_windows(values, t)
+        mw = int(max_tile_words)
         return _retrieve_block_windows_packed(
-            term_offsets, packed, fences, vals_p, value_scale,
-            term_to_shard, range_lo, range_hi, query_terms, blo, block,
-            t, int(max_tile_words), interpret, alive=alive)
+            term_offsets, packed, _word_rows(packed, mw), fences, values,
+            value_scale, term_to_shard, range_lo, range_hi, query_terms,
+            blo, block, t, mw, interpret, alive=alive)
     if use_ref:
         return retrieve_block_ref(term_offsets, doc_ids, values,
                                   term_to_shard, range_lo, range_hi,
                                   query_terms, blo, block, alive=alive)
-    dids_p, vals_p = _pad_for_windows(doc_ids, values, t)
-    return _retrieve_block_windows(term_offsets, dids_p, vals_p,
-                                   term_to_shard, range_lo, range_hi,
-                                   query_terms, blo, block, t, interpret,
-                                   alive=alive)
+    return _retrieve_block_windows(term_offsets, _id_rows(doc_ids, t),
+                                   values, term_to_shard, range_lo,
+                                   range_hi, query_terms, blo, block, t,
+                                   interpret, alive=alive)
 
 
 def csr_retrieve_topk(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
@@ -444,24 +472,25 @@ def csr_retrieve_topk(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
                     term_to_shard, range_lo, range_hi, query_terms, blo,
                     block, tile=t, spans=tuple(codec_spans), alive=alive)
         else:
-            vals_p = _pad_vals_for_windows(values, t)
+            mw = int(max_tile_words)
+            word_rows = _word_rows(packed, mw)
 
             def block_m(blo):
                 return _retrieve_block_windows_packed(
-                    term_offsets, packed, fences, vals_p, value_scale,
-                    term_to_shard, range_lo, range_hi, query_terms, blo,
-                    block, t, int(max_tile_words), interpret, alive=alive)
+                    term_offsets, packed, word_rows, fences, values,
+                    value_scale, term_to_shard, range_lo, range_hi,
+                    query_terms, blo, block, t, mw, interpret, alive=alive)
     elif use_ref:
         def block_m(blo):
             return retrieve_block_ref(term_offsets, doc_ids, values,
                                       term_to_shard, range_lo, range_hi,
                                       query_terms, blo, block, alive=alive)
     else:
-        dids_p, vals_p = _pad_for_windows(doc_ids, values, t)
+        ids = _id_rows(doc_ids, t)
 
         def block_m(blo):
             return _retrieve_block_windows(
-                term_offsets, dids_p, vals_p, term_to_shard, range_lo,
+                term_offsets, ids, values, term_to_shard, range_lo,
                 range_hi, query_terms, blo, block, t, interpret,
                 alive=alive)
 
@@ -512,7 +541,7 @@ def gather_tiles(doc_ids, values, rows, starts, *, tile: int):
     pos = (starts[:, None]
            + jnp.arange(tile, dtype=jnp.int32)[None, :]).clip(0, n - 1)
     r = rows[:, None]
-    return doc_ids[r, pos], values[r, pos]
+    return doc_ids[r, pos], gather_rows(values, r, pos)
 
 
 @partial(jax.jit, static_argnames=("tile",))
@@ -526,11 +555,9 @@ def gather_tiles_packed(packed, values, rows, starts, *, tile: int):
     the fetched tile sorted exactly like the raw path's clip-gather."""
     from ...core.codec import unpack_at
 
-    n = values.shape[1]
     pos = starts[:, None] + jnp.arange(tile, dtype=jnp.int32)[None, :]
     ids = unpack_at(*packed, rows[:, None], pos, tile=tile)
-    r = rows[:, None]
-    return ids, values[r, pos.clip(0, n - 1)]
+    return ids, gather_rows(values, rows[:, None], pos)
 
 
 @jax.jit

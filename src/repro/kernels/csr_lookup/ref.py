@@ -56,6 +56,47 @@ def bisect_steps(n: int) -> int:
     return max(int(n).bit_length(), 1)
 
 
+GATHER_CHUNK = 512   # rows per element gather (see gather_rows)
+
+
+def gather_rows(values: jnp.ndarray, k: jnp.ndarray,
+                pos: jnp.ndarray) -> jnp.ndarray:
+    """``values[k, pos]`` for ``values (K, N, n_b, n_f)`` and broadcastable
+    int32 ``k``/``pos`` (clipped into range) -> ``(..., n_b, n_f)``.
+
+    Spelled as a gather of single elements from the ``(K, n_b, n_f, N)``
+    view, not of whole ``(n_b, n_f)`` rows.  A TPU stores ``values`` in a
+    compact layout with the posting axis minor; XLA gathers elements from
+    that layout in place, while a row gather first relayouts the whole
+    operand — a per-call copy of an index that fills most of HBM.  The
+    element gather pads each element's index vector to a 128-lane row
+    (~92 KB of indices per gathered row), so rows are gathered
+    ``GATHER_CHUNK`` at a time.  The moved axis is a layout change only:
+    on every backend the result is the row gather's, bit for bit.
+    """
+    k, pos = jnp.broadcast_arrays(k, pos)
+    shape = pos.shape
+    n_b, n_f = values.shape[2], values.shape[3]
+
+    def rows(kc, pc):
+        # the view is taken inside the chunk loop: a view hoisted out of
+        # it becomes a loop operand of its own, which XLA lays out anew
+        # — a full copy of values
+        return jnp.moveaxis(values, 1, 3).at[
+            kc[:, None, None], jnp.arange(n_b)[:, None],
+            jnp.arange(n_f)[None, :], pc[:, None, None]].get(mode="clip")
+
+    kf, pf = k.reshape(-1), pos.reshape(-1)
+    n = kf.shape[0]
+    if n <= GATHER_CHUNK:
+        return rows(kf, pf).reshape(shape + (n_b, n_f))
+    c = -(-n // GATHER_CHUNK)
+    kf = jnp.pad(kf, (0, c * GATHER_CHUNK - n)).reshape(c, GATHER_CHUNK)
+    pf = jnp.pad(pf, (0, c * GATHER_CHUNK - n)).reshape(c, GATHER_CHUNK)
+    out = jax.lax.map(lambda a: rows(*a), (kf, pf))
+    return out.reshape((-1, n_b, n_f))[:n].reshape(shape + (n_b, n_f))
+
+
 def _alive_at(alive, d):
     """Tombstone gather: ``alive`` (n_docs,) bool -> mask shaped like
     ``d``.  Out-of-range ids clip to the array edge; every caller ANDs
@@ -122,6 +163,34 @@ def route_pairs(term_ids: jnp.ndarray, doc_targets: jnp.ndarray,
     return k, lo, hi
 
 
+def route_hops(term_ids: jnp.ndarray, term_offsets: jnp.ndarray,
+               term_to_shard, range_lo, n_hops: int):
+    """Every route :func:`route_pairs` can pick for each term.
+
+    term_ids (Q,) -> ``(k, lo, hi)``, each (Q, n_hops): column h is the
+    route of a pair whose doc lies past h of the term's sub-shard splits
+    — the same expressions as :func:`route_pairs` with ``hop = h`` (and
+    :func:`route_terms` at h = 0), so a kernel that counts the hop per
+    pair from the (K,) split tables lands on the identical range.  ``k``
+    is clipped to the shard count, as every gather here already clips.
+    """
+    vmax = term_offsets.shape[1] - 1
+    w = term_ids.clip(0)[:, None]
+    hop = jnp.arange(n_hops, dtype=jnp.int32)[None, :]
+    if term_to_shard is None:
+        k = jnp.zeros(w.shape, jnp.int32) + hop
+        row = w + 0 * hop
+    else:
+        k = term_to_shard.at[w].get(mode="clip").astype(jnp.int32) + hop
+        row = w - range_lo.at[k].get(mode="clip")
+    row = row.clip(0, vmax)
+    lo = term_offsets.at[k, row].get(mode="clip")
+    hi = term_offsets.at[k, (row + 1).clip(0, vmax)].get(mode="clip")
+    hi = jnp.where(term_ids[:, None] >= 0, hi, lo)   # negatives: empty
+    return (jnp.minimum(k, term_offsets.shape[0] - 1), lo.astype(jnp.int32),
+            hi.astype(jnp.int32))
+
+
 def _route(term_ids, doc_targets, term_offsets, term_to_shard, range_lo,
            split_term, split_doc):
     """Dispatch: per-term routing + broadcast when no sub-shards exist,
@@ -160,7 +229,7 @@ def lookup_pairs_ref(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
     in_list = (pos < base + hi) & (flat.at[pos].get(mode="clip") == d)
     if alive is not None:
         in_list = in_list & _alive_at(alive, d)
-    vals = values.reshape((K * N,) + values.shape[2:]).at[pos].get(mode="clip")
+    vals = gather_rows(values, k, pos - base)
     # select, not multiply-by-mask: XLA fuses the select into the gather
     # consumer, a bool-mask product materialises a second full-size pass
     # (~15% of the lookup on CPU); absent pairs are +0.0 either way
@@ -289,8 +358,8 @@ def retrieve_block_ref(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
                    jnp.broadcast_to(blo + block, lo_f.shape), n_iter=steps)
     p = s_lo[..., None] + jnp.arange(block)               # (Q, K, block)
     doc_win = flat.at[p].get(mode="clip")
-    flat_vals = values.reshape((k_n * n,) + values.shape[2:])
-    val_win = flat_vals.at[p].get(mode="clip")
+    ks = jnp.arange(k_n, dtype=jnp.int32)[None, :, None]
+    val_win = gather_rows(values, ks, p - ks * n)
     return merge_windows(doc_win, val_win, s_hi - s_lo, blo, block,
                          alive=alive)
 
@@ -324,7 +393,7 @@ def csr_lookup_ref(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
     in_list = (pos < hi_f) & (flat.at[pos].get(mode="clip") == d)
     if alive is not None:
         in_list = in_list & _alive_at(alive, d)
-    vals = values.reshape((K * N,) + values.shape[2:]).at[pos].get(mode="clip")
+    vals = gather_rows(values, k, pos - k * N)
     # select over multiply-by-mask: see lookup_pairs_ref
     return jnp.where(in_list[..., None, None], vals, 0.0)
 
@@ -471,7 +540,6 @@ def _lookup_packed(term_offsets, packed, fences, values, value_scale,
     found = (pos < hi) & (v_at == d)
     if alive is not None:
         found = found & _alive_at(alive, d)
-    flat = values.reshape((k_n * nmax,) + values.shape[2:])
     if value_scale is not None:
         # int8 dequant: convert+scale fused into the gather consumer, one
         # full-size select at the end.  The barrier pins the (tiny,
@@ -479,12 +547,12 @@ def _lookup_packed(term_offsets, packed, fences, values, value_scale,
         # without it XLA threads the bisect producer chain into the
         # gather loop and the dequant pass runs ~1.4x slower on CPU.
         scale = _lane_scale(value_scale, range_lo, k, term_ids)
-        ix, sc, fd = jax.lax.optimization_barrier(
-            (k * nmax + pos, scale, found))
-        vals = flat.at[ix].get(mode="clip").astype(jnp.float32)
+        kk, ix, sc, fd = jax.lax.optimization_barrier(
+            (k, pos, scale, found))
+        vals = gather_rows(values, kk, ix).astype(jnp.float32)
         return jnp.where(fd[..., None, None], vals * sc[..., None, None], 0.0)
-    ix, fd = jax.lax.optimization_barrier((k * nmax + pos, found))
-    vals = flat.at[ix].get(mode="clip")
+    kk, ix, fd = jax.lax.optimization_barrier((k, pos, found))
+    vals = gather_rows(values, kk, ix)
     # select over multiply-by-mask: see lookup_pairs_ref
     return jnp.where(fd[..., None, None], vals, 0.0)
 
@@ -549,8 +617,7 @@ def retrieve_block_packed_ref(term_offsets, packed, fences, values,
                          tile=tile, spans=spans)
     p = s_lo[..., None] + jnp.arange(block)               # (Q, K, block)
     doc_win = unpack_at(*packed, ks[..., None], p, tile=tile)
-    flat_p = jnp.clip(base[..., None] + p, 0, k_n * nmax - 1)
-    val_win = values.reshape((k_n * nmax,) + values.shape[2:])[flat_p]
+    val_win = gather_rows(values, ks[..., None], p)
     if value_scale is not None:
         scale = _lane_scale(value_scale, range_lo, ks, query_terms[:, None])
         val_win = val_win.astype(jnp.float32) * scale[..., None, None, None]

@@ -47,7 +47,9 @@ def main() -> None:
     ap.add_argument("--compare-noindex", action="store_true")
     ap.add_argument("--data-parallel", action="store_true",
                     help="shard the index over the host mesh and score "
-                         "candidate batches data-parallel (dist.sharding)")
+                         "candidate batches data-parallel (dist.sharding); "
+                         "with --partition term the term shards spread "
+                         "over the devices instead (mesh model axis)")
     ap.add_argument("--partition", choices=["none", "term"], default="none",
                     help="'term': split posting lists into nnz-balanced "
                          "term-range shards (PartitionedIndex) instead of "
@@ -126,6 +128,8 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from .. import use_compile_cache
+    use_compile_cache()
     from ..configs import seine_smoke
     from ..core import (HashProvider, IndexBuilder, build_vocabulary,
                         segment_corpus)
@@ -259,7 +263,11 @@ def main() -> None:
     mesh = None
     if args.data_parallel:
         from .mesh import make_host_mesh
-        mesh = make_host_mesh(data=len(jax.devices()))
+        # term shards go on the model axis — one shard per device; a
+        # data-only mesh would replicate every shard on every device
+        n_dev = len(jax.devices())
+        mesh = (make_host_mesh(model=n_dev) if args.partition == "term"
+                else make_host_mesh(data=n_dev))
         _log.info("data-parallel", devices=mesh.devices.size,
                   mesh=dict(zip(mesh.axis_names, mesh.devices.shape)))
     engine = SeineEngine(

@@ -23,11 +23,8 @@ def maybe_constrain(x: jnp.ndarray, *axes) -> jnp.ndarray:
     a no-op. The pseudo-axis "__data__" expands to every batch-parallel
     axis present ("pod", "data").
     """
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        names = tuple(mesh.axis_names)
-    except Exception:  # noqa: BLE001
-        return x
+    mesh = jax.sharding.get_abstract_mesh()
+    names = tuple(mesh.axis_names)
     if not names:
         return x
 
@@ -70,11 +67,7 @@ def maybe_replicate(x: jnp.ndarray) -> jnp.ndarray:
     the SLICED layer weights to replicated places the all-gather inside the
     loop (it depends on the slice index, so XLA cannot hoist it), giving
     true per-layer gather/release instead of a whole-model gather."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if not tuple(mesh.axis_names):
-            return x
-    except Exception:  # noqa: BLE001
+    if not tuple(jax.sharding.get_abstract_mesh().axis_names):
         return x
     from jax.sharding import PartitionSpec as _P
     return jax.lax.with_sharding_constraint(x, _P())

@@ -165,10 +165,7 @@ def moe_ffn(x: jnp.ndarray, lp: Params, cfg: TransformerConfig,
     # tp16) the capacity dim carries the model sharding instead
     # (TP-within-expert layout). The zero buffer is pinned BEFORE the
     # scatter so the scatter itself is partitioned.
-    try:
-        _msize = dict(jax.sharding.get_abstract_mesh().shape).get("model", 1)
-    except Exception:  # noqa: BLE001
-        _msize = 1
+    _msize = dict(jax.sharding.get_abstract_mesh().shape).get("model", 1)
     if E % max(_msize, 1) == 0 and batch_axes == "__data__":
         _spec = ("__data__", "model", None, None)     # EP layout
     elif batch_axes == "__data__":

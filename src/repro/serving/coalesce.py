@@ -175,71 +175,55 @@ class CoalescingScorer:
         self.cache = cache
         self.pair_pad = int(pair_pad)
         self._live = bool(getattr(engine.index, "is_live", False))
-        index, spec = self.index, self.spec
+        spec = self.spec
 
+        # every jit takes the index — or, live, a LiveView — as a pytree
+        # ARGUMENT (the engine's pattern): jit would embed a closed-over
+        # index in the program as literal constants, and compiled
+        # programs keyed on shapes always consume the snapshot the batch
+        # pinned.  Live, score_batch pins ONE view for its whole batch
+        # (_current_view), so the lookup, the delta tail and every
+        # per-request score see the same snapshot even if mutations land
+        # mid-batch.
         self._batch_view = None
-        if self._live:
-            # live index: every jit takes a LiveView as a pytree
-            # ARGUMENT (the same pattern as the engine's live mode), so
-            # compiled programs are keyed on shapes and always consume
-            # the snapshot the batch pinned — a captured-index jit would
-            # serve trace-time arrays forever.  score_batch pins ONE
-            # view for its whole batch (_current_view), so the lookup,
-            # the delta tail and every per-request score see the same
-            # snapshot even if mutations land mid-batch.
-            def pair_lookup_view(view, t, d):
-                return view.lookup_pairs(t[:, None], d)[:, 0]
 
-            self._plv = jax.jit(pair_lookup_view)
-            self._pair_lookup = (
-                lambda t, d: self._plv(self._current_view(), t, d))
+        def pair_lookup(view, t, d):
+            # (P,) x (P,) -> (P, n_b, n_f): lookup_pairs takes (..., Q)
+            # term ids against (...,) docs, so a Q=1 axis is added and
+            # stripped — one routed bisect per distinct pair, on the raw
+            # or packed path the index dispatches
+            return view.lookup_pairs(t[:, None], d)[:, 0]
 
-            def pair_tail_view(view, t, d, base_vals):
-                # the tile cache resolved the pairs against view.base
-                # only (it binds one immutable generation): add the
-                # delta's rows — exclusive doc-space ownership makes the
-                # sum exact — and fold the tombstone mask
-                if view.delta is not None:
-                    base_vals = base_vals \
-                        + view.delta.lookup_pairs(t[:, None], d)[:, 0]
-                if view.alive is not None:
-                    dead_ok = view.alive.at[d].get(mode="clip")
-                    base_vals = jnp.where(dead_ok[:, None, None],
-                                          base_vals, 0.0)
-                return base_vals
+        self._plv = jax.jit(pair_lookup)
+        self._pair_lookup = (
+            lambda t, d: self._plv(self._current_view(), t, d))
 
-            self._pair_tail = jax.jit(pair_tail_view)
+        def pair_tail_view(view, t, d, base_vals):
+            # the tile cache resolved the pairs against view.base only
+            # (it binds one immutable generation): add the delta's rows
+            # — exclusive doc-space ownership makes the sum exact — and
+            # fold the tombstone mask
+            if view.delta is not None:
+                base_vals = base_vals \
+                    + view.delta.lookup_pairs(t[:, None], d)[:, 0]
+            if view.alive is not None:
+                dead_ok = view.alive.at[d].get(mode="clip")
+                base_vals = jnp.where(dead_ok[:, None, None],
+                                      base_vals, 0.0)
+            return base_vals
 
-            def score_one_view(params, view, vals, inv, query_terms,
-                               doc_ids):
-                m = vals[inv].reshape(
-                    (doc_ids.shape[0], query_terms.shape[0])
-                    + vals.shape[1:])
-                meta = make_qmeta(view, query_terms, doc_ids)
-                return spec.score(params, m, meta, view.functions)
+        self._pair_tail = jax.jit(pair_tail_view)
 
-            sov = jax.jit(score_one_view)
-            self._score_one = (
-                lambda params, vals, inv, q, d:
-                sov(params, self._current_view(), vals, inv, q, d))
-        else:
-            def pair_lookup(t, d):
-                # (P,) x (P,) -> (P, n_b, n_f): lookup_pairs takes
-                # (..., Q) term ids against (...,) docs, so a Q=1 axis is
-                # added and stripped — one routed bisect per distinct
-                # pair, on the raw or packed path the index dispatches
-                return index.lookup_pairs(t[:, None], d)[:, 0]
+        def score_one(params, view, vals, inv, query_terms, doc_ids):
+            m = vals[inv].reshape(
+                (doc_ids.shape[0], query_terms.shape[0]) + vals.shape[1:])
+            meta = make_qmeta(view, query_terms, doc_ids)
+            return spec.score(params, m, meta, view.functions)
 
-            self._pair_lookup = jax.jit(pair_lookup)
-
-            def score_one(params, vals, inv, query_terms, doc_ids):
-                m = vals[inv].reshape(
-                    (doc_ids.shape[0], query_terms.shape[0])
-                    + vals.shape[1:])
-                meta = make_qmeta(index, query_terms, doc_ids)
-                return spec.score(params, m, meta, index.functions)
-
-            self._score_one = jax.jit(score_one)
+        sov = jax.jit(score_one)
+        self._score_one = (
+            lambda params, vals, inv, q, d:
+            sov(params, self._current_view(), vals, inv, q, d))
         self._pairs_counter = obs.counter(
             "seine_coalesce_pair_slots_total",
             "pre-dedupe (term, doc) pair slots submitted")
@@ -251,8 +235,10 @@ class CoalescingScorer:
             "distinct / submitted pair slots, last batch")
 
     def _current_view(self):
-        """The batch-pinned LiveView, or a fresh snapshot outside a
-        batch (live mode only)."""
+        """The index the jits read: the batch-pinned LiveView, a fresh
+        snapshot outside a batch, or the static index itself."""
+        if not self._live:
+            return self.index
         v = self._batch_view
         return v if v is not None else self.index.view
 

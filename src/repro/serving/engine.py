@@ -208,23 +208,18 @@ class SeineEngine:
         # (partial-sum merge -> all-reduce over the model axis)
         self._lookup_impl = "jnp" if mesh is not None else "fused"
         self._lookup_tile = lookup_tile
-        if self._live:
-            # live mode: the jitted programs take the current LiveView as
-            # a pytree argument — compiled code is keyed on array shapes,
-            # never on array VALUES, so inserts/deletes/compactions are
-            # picked up by the very next call (a captured-constant jit
-            # would silently serve the trace-time snapshot forever)
-            score_view = jax.jit(self._score_view_impl)
-            self._score = (lambda params, qt, docs:
-                           score_view(params, self.index.view, qt, docs))
-            retrieve_view = jax.jit(self._retrieve_view_impl,
-                                    static_argnames=("k", "doc_block"))
-            self._retrieve = (
-                lambda params, qt, *, k, doc_block:
-                retrieve_view(params, self.index.view, qt, k=k,
-                              doc_block=doc_block))
-        else:
-            self._score = jax.jit(self._score_impl)
+        # the jitted programs take the index as a pytree ARGUMENT, never
+        # as a closure: jit embeds closed-over arrays in the program as
+        # literal constants — a copy of a multi-GB index inside the HLO —
+        # and a LiveIndex mutates underneath the engine, so its programs
+        # must serve whatever snapshot (``_served()``) the call reads.
+        # Compiled code is keyed on shapes, never on array values.
+        self._score = jax.jit(self._score_impl)
+        # first-stage retrieval: one jit per static k (jax caches per
+        # (k, doc_block) pair); retrieve() trims k > n_docs before jitting
+        # so a sweep of oversized ks shares one compiled program
+        self._retrieve = jax.jit(self._retrieve_impl,
+                                 static_argnames=("k", "doc_block"))
         # sampled lookup-stats state (mesh-less only; see score()).  The
         # found-count helper is a SEPARATE lazy jit so sampling can never
         # perturb the gated ``_score`` program or its compile cache.
@@ -238,12 +233,6 @@ class SeineEngine:
         # then run in flush_lookup_stats(), outside the timed region
         self.defer_lookup_stats = False
         self._pending_stats = None
-        # first-stage retrieval: one jit per static k (jax caches per
-        # (k, doc_block) pair); retrieve() trims k > n_docs before jitting
-        # so a sweep of oversized ks shares one compiled program
-        if not self._live:
-            self._retrieve = jax.jit(self._retrieve_impl,
-                                     static_argnames=("k", "doc_block"))
         self._retrieves_counter = obs.counter(
             "seine_engine_retrieves_total", "engine.retrieve calls")
         # per-call registry lookups hoisted to construction: score() is
@@ -265,41 +254,21 @@ class SeineEngine:
                       "posting tiles per shard (ceil(Nmax / tile))").set(
                 -(-nmax // tile))
 
-    def _score_impl(self, params, query_terms, doc_ids):
-        m = self.index.qd_matrix(query_terms, doc_ids,
-                                 impl=self._lookup_impl,
-                                 tile=self._lookup_tile)
-        meta = make_qmeta(self.index, query_terms, doc_ids)
-        return self.spec.score(params, m, meta, self.index.functions)
+    def _served(self):
+        """What the jitted programs read: the index, or a LiveIndex's
+        current snapshot (a LiveView pytree)."""
+        return self.index.view if self._live else self.index
 
-    def _score_view_impl(self, params, view, query_terms, doc_ids):
-        """Live-mode scorer: identical math to :meth:`_score_impl`, but
-        every index array comes in through ``view`` (a LiveView pytree
-        argument), so the compiled program serves whatever snapshot the
-        caller just read."""
-        m = view.qd_matrix(query_terms, doc_ids, impl=self._lookup_impl,
-                           tile=self._lookup_tile)
-        meta = make_qmeta(view, query_terms, doc_ids)
-        return self.spec.score(params, m, meta, view.functions)
+    def _score_impl(self, params, index, query_terms, doc_ids):
+        m = index.qd_matrix(query_terms, doc_ids, impl=self._lookup_impl,
+                            tile=self._lookup_tile)
+        meta = make_qmeta(index, query_terms, doc_ids)
+        return self.spec.score(params, m, meta, index.functions)
 
-    def _retrieve_view_impl(self, params, view, query_terms, k, doc_block):
-        """Live-mode first-stage retrieval over a LiveView argument —
-        the base drives the block scan, the delta joins through the
-        driver's ``extra_m_fn`` hook, tombstones mask to ``-inf``."""
-        n_docs = view.n_docs
-
-        def score_block(m, docs):
-            d = docs.clip(0, n_docs - 1)
-            meta = make_qmeta(view, query_terms, d)
-            return self.spec.score(params, m, meta, view.functions)
-
-        return view.retrieve_topk(query_terms, k, score_block,
-                                  doc_block=doc_block,
-                                  impl=self._lookup_impl,
-                                  tile=self._lookup_tile)
-
-    def _retrieve_impl(self, params, query_terms, k, doc_block):
-        index = self.index
+    def _retrieve_impl(self, params, index, query_terms, k, doc_block):
+        """First-stage retrieval over ``index``; for a LiveView the base
+        drives the block scan, the delta joins through the scan's
+        ``extra_m_fn`` hook and tombstones mask to ``-inf``."""
         n_docs = index.n_docs
 
         def score_block(m, docs):
@@ -345,8 +314,8 @@ class SeineEngine:
                 self.index.n_docs)
             obs.gauge("seine_retrieve_last_k",
                       "k of the most recent retrieve").set(kk)
-        return self._retrieve(self.params, query_terms, k=kk,
-                              doc_block=doc_block)
+        return self._retrieve(self.params, self._served(), query_terms,
+                              k=kk, doc_block=doc_block)
 
     def flush_lookup_stats(self) -> None:
         """Run a deferred sampled-stats lookup, if one is staged.
@@ -373,7 +342,8 @@ class SeineEngine:
                 jax.device_put(doc_ids, NamedSharding(self.mesh, spec)))
 
     def _make_found_fn(self):
-        """(query_terms (Q,), doc_ids (B,)) -> (found pairs, valid pairs).
+        """(index, query_terms (Q,), doc_ids (B,)) -> (found pairs, valid
+        pairs).
 
         Built on the same ownership logic as the jnp lookup but returning
         only the found mask — a lazy jit, compiled on the first sampled
@@ -384,9 +354,9 @@ class SeineEngine:
             # live: the module-level jit takes the view as an argument,
             # so the sampled stats track mutations like the scorer does
             from ..dist.live import found_counts
-            return lambda qt, docs: found_counts(index.view, qt, docs)
+            return found_counts
         if not isinstance(index, PartitionedIndex):
-            def impl(qt, docs):
+            def impl(index, qt, docs):
                 q = jnp.broadcast_to(qt[None], (docs.shape[0],) + qt.shape)
                 _, found = index.lookup_positions(q, docs)
                 return found.sum(), (q >= 0).sum()
@@ -399,7 +369,7 @@ class SeineEngine:
             # probe only)
             from ..kernels.csr_lookup.ref import _route, packed_bisect
 
-            def impl(qt, docs):
+            def impl(index, qt, docs):
                 q = jnp.broadcast_to(qt[None], (docs.shape[0],) + qt.shape)
                 d = jnp.broadcast_to(docs[..., None], q.shape)
                 valid = q >= 0
@@ -415,14 +385,14 @@ class SeineEngine:
             return jax.jit(impl)
 
         from ..core.index import csr_lookup_positions
-        range_hi = index.range_hi
 
-        def impl(qt, docs):
+        def impl(index, qt, docs):
             q = jnp.broadcast_to(qt[None], (docs.shape[0],) + qt.shape)
             w = q.clip(0)
             d = jnp.broadcast_to(docs[..., None], q.shape)
             valid = q >= 0
             shard_of = index.term_to_shard.at[w].get(mode="clip")
+            range_hi = index.range_hi
 
             def partial(offsets_k, docs_k, lo_k, hi_k, k):
                 owned = ((shard_of == k) if range_hi is None
@@ -453,7 +423,8 @@ class SeineEngine:
             # host copy once per generation
             self._t2s_host = np.asarray(self.index.term_to_shard)
             self._t2s_gen = self.index.generation
-        found, total = self._found_fn(query_terms, doc_ids)
+        found, total = self._found_fn(self._served(), query_terms,
+                                      doc_ids)
         found, total = int(found), int(total)
         obs.counter("seine_lookup_found_total",
                     "found pairs (sampled)").inc(found)
@@ -504,7 +475,8 @@ class SeineEngine:
                     self._pending_stats = (query_terms, doc_ids)
                 else:
                     self._sample_lookup_stats(query_terms, doc_ids)
-        return self._score(self.params, query_terms, doc_ids)
+        return self._score(self.params, self._served(), query_terms,
+                           doc_ids)
 
 
 class NoIndexEngine:

@@ -122,7 +122,7 @@ class PostingTileCache:
         # THIS index generation (rebuilt on swap, so it can never read a
         # stale generation either)
         self._fallback = jax.jit(
-            lambda t, d: index.lookup_pairs(t[:, None], d)[:, 0])
+            lambda idx, t, d: idx.lookup_pairs(t[:, None], d)[:, 0])
         self._size_gauge.set(0)
 
     def swap_index(self, index) -> None:
@@ -295,7 +295,8 @@ class PostingTileCache:
             fd = np.zeros(p, np.int32)
             ft[:n] = terms[ix]
             fd[:n] = docs[ix]
-            rows = self._fallback(jnp.asarray(ft), jnp.asarray(fd))[:n]
+            rows = self._fallback(self.index, jnp.asarray(ft),
+                                  jnp.asarray(fd))[:n]
             out = out.at[jnp.asarray(ix.astype(np.int32))].set(rows)
         return out
 

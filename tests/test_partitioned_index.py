@@ -261,6 +261,22 @@ class TestPartitionStructure:
         np.testing.assert_array_equal(np.asarray(p.qd_matrix(q, docs)),
                                       np.asarray(idx.qd_matrix(q, docs)))
 
+    @pytest.mark.parametrize("dtype", [np.float32, np.int8])
+    def test_device_values_in_chunks_is_exact(self, dtype):
+        """Index-sized values move to the device, and back, a chunk of
+        postings at a time (the last chunk overlapping); the array that
+        lands is the source array, bit for bit."""
+        from repro.dist.partition import device_values, host_values
+        rng = np.random.RandomState(0)
+        host = (rng.randn(3, 1001, 4, 3) * 50).astype(dtype)
+        chunk = host[:, :128].nbytes
+        got = device_values(host, chunk_bytes=chunk)
+        assert got.dtype == host.dtype
+        np.testing.assert_array_equal(np.asarray(got), host)
+        back = host_values(got, chunk_bytes=chunk)
+        assert back.dtype == host.dtype
+        np.testing.assert_array_equal(back, host)
+
     def test_hot_term_skew_warns_without_split(self, hot_term_index):
         """split_hot=False restores the old term-aligned-only plan: the
         unsplittable hot list pads every shard up to it — warned — and
@@ -380,6 +396,24 @@ class TestEngineInit:
         mesh = make_host_mesh(data=len(jax.devices()))
         meng = SeineEngine(w["index"], "knrm", params, mesh=mesh)
         assert meng._data_axes != () and meng._lookup_impl == "jnp"
+
+    @pytest.mark.parametrize("k", [1, 4])
+    def test_programs_take_the_index_as_an_argument(self, seine_world, k):
+        """jit embeds closed-over arrays as literal constants: a scoring
+        program that closed over the index would carry all of it in its
+        text (at MQ2007 scale ~10 GB).  The engine passes it in instead,
+        so the program text stays far smaller than the values."""
+        w = seine_world
+        pidx = partition_index(w["index"], k)
+        spec = get_retriever("knrm")
+        params = spec.init(jax.random.key(0), pidx.n_b, pidx.functions)
+        eng = SeineEngine(pidx, "knrm", params)
+        q = jnp.asarray(np.arange(8, dtype=np.int32))
+        d = jnp.asarray(np.arange(16, dtype=np.int32))
+        text = eng._score.lower(params, eng.index, q, d).as_text()
+        assert len(text) < pidx.values.nbytes / 4
+        np.testing.assert_array_equal(np.asarray(eng.score(q, d)),
+                                      np.asarray(eng.score(q, d)))
 
 
 class TestServeStatsPercentiles:
