@@ -57,12 +57,6 @@ import numpy as np
 
 from .common import emit
 
-# the sampled found-mask stats cost a real device lookup every N-th
-# engine.score() call — the naive path calls engine.score per request
-# and the coalesced paths do not, so sampling would bias the gated
-# ratio.  Effectively disable it before any engine is constructed.
-os.environ.setdefault("REPRO_OBS_SAMPLE", "1000000000")
-
 N_DOCS = int(os.environ.get("REPRO_BENCH_FRONTEND_DOCS", 60000))
 VOCAB = 8000
 CODEC = "packed"

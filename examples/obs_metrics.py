@@ -6,7 +6,8 @@ index-build-and-serve lifecycle (the repro.obs quick-start, runnable).
 Builds the smoke index (instrumented build stages fill the
 ``seine_build_*`` counters and ``build.stage*`` spans), partitions it
 (``seine_shard_*`` balance gauges), serves a few batched requests
-(``seine_serve_*`` + the sampled ``seine_lookup_*`` hit-rate stats),
+(``seine_serve_*``, ``seine_lookup_pairs_total`` and the
+``engine.score`` / ``serve.request`` spans),
 then shows the three export surfaces:
 
 * ``obs.to_prometheus()``  — Prometheus text exposition (what
@@ -66,7 +67,7 @@ def main() -> None:
     for name in ("seine_build_docs_total", "seine_shard_nnz",
                  "seine_serve_requests_total",
                  "seine_serve_degenerate_requests_total",
-                 "seine_lookup_found_ratio"):
+                 "seine_lookup_pairs_total"):
         for labels, value in obs.REGISTRY.get(name).samples():
             tag = "".join(f"{{{k}={v}}}" for k, v in labels)
             print(f"  {name}{tag} = {value:g}")

@@ -29,6 +29,7 @@ from .. import obs
 
 _SEP = "/"
 _log = obs.get_logger("repro.ckpt")
+_PUBLISH = threading.Lock()      # this process's checkpoint publishes
 
 
 def _flatten_with_paths(tree) -> List[Tuple[str, Any]]:
@@ -75,11 +76,15 @@ def save_checkpoint(ckpt_dir: str, step: int, tree: Any, *,
                 np.savez(os.path.join(tmp, "arrays.npz"), **arrays)
                 with open(os.path.join(tmp, "manifest.json"), "w") as f:
                     json.dump(manifest, f)
-                if os.path.exists(final):
-                    import shutil
-                    shutil.rmtree(final)
-                os.replace(tmp, final)          # atomic publish
-                _retain(ckpt_dir, keep)
+                # ... nor interleave their publishes: one writer's
+                # replace would meet the directory the other had just
+                # published there ("Directory not empty")
+                with _PUBLISH:
+                    if os.path.exists(final):
+                        import shutil
+                        shutil.rmtree(final)
+                    os.replace(tmp, final)      # atomic publish
+                    _retain(ckpt_dir, keep)
             obs.counter("seine_ckpt_saves_total",
                         "checkpoint publishes").inc()
         except BaseException as e:

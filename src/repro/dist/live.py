@@ -198,8 +198,8 @@ def _index_found(pidx: PartitionedIndex, w: jnp.ndarray, d: jnp.ndarray
 @jax.jit
 def found_counts(view: LiveView, query_terms: jnp.ndarray,
                  doc_ids: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """(found pairs, valid pairs) over the live view — the sampled
-    lookup-stats helper :class:`~repro.serving.engine.SeineEngine` uses.
+    """(found pairs, valid pairs) of ``query_terms`` x ``doc_ids`` over
+    the live view: which pairs the view resolves, tombstones applied.
     The view rides through jit as an argument, so the compiled program
     never goes stale across inserts/deletes/compactions."""
     q = jnp.broadcast_to(query_terms[None],

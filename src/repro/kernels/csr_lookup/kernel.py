@@ -223,6 +223,7 @@ def csr_lookup_pallas(lane_k: jnp.ndarray, lane_lo: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((q_n, b_n), jnp.int32),
         interpret=interpret,
+        name="seine_csr_positions",
     )(lane_k, lane_lo, lane_hi, query_terms, split_term, split_doc,
       doc_targets, *tables, rows)
 
@@ -267,4 +268,5 @@ def window_rows_pallas(row0: jnp.ndarray, rows: jnp.ndarray, *,
         out_shape=jax.ShapeDtypeStruct((n_lanes, n_win, n_rows, 1, LANES),
                                        jnp.int32),
         interpret=interpret,
+        name="seine_window_rows",
     )(row0.astype(jnp.int32), rows)

@@ -97,28 +97,31 @@ def _lookup_found(term_offsets, term_to_shard, range_lo, split_term,
     (``values[k, pos]`` where found, exact zeros elsewhere; q8 dequant
     by the pair's per-term scale), so M is bitwise-equal to them."""
     hops = 1 if split_term is None else term_offsets.shape[0]
-    k, lo, hi = route_hops(query_terms, term_offsets, term_to_shard,
-                           range_lo, hops)
-    if split_term is None:                 # one entry that never matches
-        split_term = jnp.full((1,), -1, jnp.int32)
-        split_doc = jnp.zeros((1,), jnp.int32)
     n = values.shape[1]
-    pos = csr_lookup_pallas(
-        k, lo, hi, query_terms.clip(0).astype(jnp.int32),
-        split_term.astype(jnp.int32), split_doc.astype(jnp.int32),
-        doc_targets.astype(jnp.int32), fences, rows, tile=tile,
-        stride=stride, n_pos=n, packed_meta=packed_meta,
-        max_tile_words=max_tile_words, interpret=interpret)
-    flat = pos.T                                          # (B, Q)
-    found = (flat >= 0)[..., None, None]
-    f = jnp.maximum(flat, 0)
-    kk = f // n
-    vals = gather_rows(values, kk, f - kk * n)
-    if value_scale is not None:
-        sc = _lane_scale(value_scale, range_lo, kk, query_terms[None, :])
-        return jnp.where(found, vals.astype(jnp.float32)
-                         * sc[..., None, None], 0.0)
-    return jnp.where(found, vals, 0.0)
+    with jax.named_scope("seine.lookup.positions"):
+        k, lo, hi = route_hops(query_terms, term_offsets, term_to_shard,
+                               range_lo, hops)
+        if split_term is None:             # one entry that never matches
+            split_term = jnp.full((1,), -1, jnp.int32)
+            split_doc = jnp.zeros((1,), jnp.int32)
+        pos = csr_lookup_pallas(
+            k, lo, hi, query_terms.clip(0).astype(jnp.int32),
+            split_term.astype(jnp.int32), split_doc.astype(jnp.int32),
+            doc_targets.astype(jnp.int32), fences, rows, tile=tile,
+            stride=stride, n_pos=n, packed_meta=packed_meta,
+            max_tile_words=max_tile_words, interpret=interpret)
+    with jax.named_scope("seine.lookup.gather"):
+        flat = pos.T                                      # (B, Q)
+        found = (flat >= 0)[..., None, None]
+        f = jnp.maximum(flat, 0)
+        kk = f // n
+        vals = gather_rows(values, kk, f - kk * n)
+        if value_scale is not None:
+            sc = _lane_scale(value_scale, range_lo, kk,
+                             query_terms[None, :])
+            return jnp.where(found, vals.astype(jnp.float32)
+                             * sc[..., None, None], 0.0)
+        return jnp.where(found, vals, 0.0)
 
 
 @partial(jax.jit,
@@ -180,9 +183,11 @@ def csr_lookup(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
                 split_term, split_doc, tile=t, spans=tuple(codec_spans),
                 alive=alive)
         mw = int(max_tile_words)
+        with jax.named_scope("seine.lookup.ids"):
+            rows = _word_rows(packed, mw)
         out = _lookup_found(
             term_offsets, term_to_shard, range_lo, split_term, split_doc,
-            query_terms, doc_targets, fences, _word_rows(packed, mw),
+            query_terms, doc_targets, fences, rows,
             values, value_scale, tile=t, stride=packed[0].shape[1],
             packed_meta=(packed[2], packed[3]), max_tile_words=mw,
             interpret=bool(interpret))
@@ -192,11 +197,14 @@ def csr_lookup(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
                               range_lo, query_terms, doc_targets,
                               split_term, split_doc, alive=alive)
     n_fence = fence_count(doc_ids.shape[1], t)
-    # stored fences are spaced at the build-time POSTING_TILE — rebuild
-    # whenever the requested tile disagrees (the parity sweep's override)
-    if fences is None or t != POSTING_TILE or fences.shape[1] != n_fence:
-        fences = build_fences(doc_ids, t)
-    _, rows = _id_rows(doc_ids, t)
+    with jax.named_scope("seine.lookup.ids"):
+        # stored fences are spaced at the build-time POSTING_TILE —
+        # rebuild whenever the requested tile disagrees (the parity
+        # sweep's override)
+        if (fences is None or t != POSTING_TILE
+                or fences.shape[1] != n_fence):
+            fences = build_fences(doc_ids, t)
+        _, rows = _id_rows(doc_ids, t)
     out = _lookup_found(
         term_offsets, term_to_shard, range_lo, split_term, split_doc,
         query_terms, doc_targets, fences, rows, values, None, tile=t,

@@ -372,7 +372,8 @@ def main() -> None:
                   served=res.n_served, rejected=res.n_rejected,
                   goodput=f"{res.goodput:.3f}",
                   ms_per_request=f"{stats.ms_per_request:.2f}",
-                  p50=f"{stats.p50_ms:.2f}", p95=f"{stats.p95_ms:.2f}",
+                  p50=f"{res.p50_ms:.2f}", p95=f"{res.p95_ms:.2f}",
+                  late_p95=f"{np.percentile(res.late_ms, 95):.2f}",
                   queue_ms=f"{stats.queue_ms_per_request:.2f}",
                   max_queue_depth=stats.max_queue_depth,
                   coalesce=args.coalesce, cache_tiles=args.cache_tiles)

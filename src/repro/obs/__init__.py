@@ -10,7 +10,9 @@ Pure Python, zero deps, process-local.  Three pieces:
   sections and A/B overhead tests;
 * :mod:`~repro.obs.trace` — nesting timing spans
   (``with obs.span("build.stage2.interact"): ...``) aggregated per
-  name, with optional Chrome-trace / ``jax.profiler`` emission;
+  name, plus an optional bounded record of each span's enter and exit
+  on the host's ``perf_counter_ns`` clock (``obs.record_events(n)``,
+  ``obs.events()``; off by default);
 * :mod:`~repro.obs.export` — Prometheus text + JSON snapshot exporters
   (``obs.write_metrics("out.prom")``, ``obs.dump()``) and a parser for
   round-trip tests; :mod:`~repro.obs.log` — the structured stderr
@@ -18,11 +20,11 @@ Pure Python, zero deps, process-local.  Three pieces:
   the scattered ``print()`` telemetry moved onto.
 
 Nothing here ever runs *inside* a jit trace: instrumentation sits at the
-Python call boundaries (engine/serve/build loops), so the fused serving
-kernel and the gated bench latencies are untouched.  Found-mask /
-routing statistics are additionally *sampled* (every
-``REPRO_OBS_SAMPLE``-th request, default 16) because they cost a real
-device lookup.
+Python call boundaries (engine/serve/build loops) and reads only host
+arrays, so the served program and the measured latencies are untouched:
+no metric runs a device program or waits on one.  Inside the served
+program, ``jax.named_scope`` names its parts instead (see the span list
+below), which a device trace attributes each operation to.
 
 Quick start (see examples/obs_metrics.py for the runnable version)::
 
@@ -97,12 +99,8 @@ seine_live_deletes_total              counter   doc ids tombstoned
 seine_live_compactions_total          counter   compactions folded into base
 seine_live_compaction_errors_total    counter   background compaction failures
 seine_frontend_epoch_swaps_total      counter   frontend engine epoch swaps
-seine_lookup_found_ratio              gauge     found-mask hit rate (sampled)
-seine_lookup_found_total              counter   found pairs (sampled)
-seine_lookup_pairs_sampled_total      counter   looked-up pairs (sampled)
-seine_lookup_pairs_total              counter   routed pairs {shard=k} (smpl)
-seine_lookup_tiles_per_shard          gauge     ceil(Nmax / posting tile)
-seine_lookup_tile_dmas_per_query      gauge     tile DMAs per query (sampled)
+seine_lookup_pairs_total              counter   (term, candidate) pairs served:
+                                                valid terms x real candidates
 seine_heartbeat_ranks                 gauge     ranks ever seen
 seine_heartbeat_age_seconds           gauge     since last beat {rank=r}
 seine_heartbeat_dead_ranks            gauge     ranks past the deadline
@@ -122,11 +120,25 @@ seine_span_last_seconds               gauge     last span duration {span=}
 
 Span names follow the lifecycle: ``build.stream_runs`` /
 ``build.stage1.uniq``..``build.stage4.merge``, ``serve.request`` /
-``serve.retrieve`` / ``frontend.batch``, ``ckpt.save`` /
-``ckpt.save_index``, ``train.step``, and the live-index pair
-``live.ingest`` / ``live.compact`` (the background merge, so compaction
-wall-time shows up in ``seine_span_seconds_total`` even though it never
-blocks a query).
+``serve.retrieve``, ``ckpt.save`` / ``ckpt.save_index``, ``train.step``,
+and the live-index pair ``live.ingest`` / ``live.compact`` (the
+background merge, so compaction wall-time shows up in
+``seine_span_seconds_total`` even though it never blocks a query).
+
+The frontend's worker thread (``seine-frontend``) runs, per batch:
+``frontend.form`` (from the first request's dequeue to the batch's
+close, the ``batch_timeout_ms`` wait included; attr ``size``), then
+``frontend.batch`` around ``engine.score`` (one per request: argument
+conversion and the jit dispatch, up to the call's return),
+``frontend.wait`` (``block_until_ready`` of one request's scores) and
+``frontend.answer`` (device-to-host copy, unpad, ``set_result``).
+
+Named scopes inside the served program (module ``jit_seine_score``):
+``seine.lookup.ids`` (the tile pad of the posting ids),
+``seine.lookup.positions`` (routing and the ``seine_csr_positions``
+Pallas kernel), ``seine.lookup.gather`` (the value-row gather and the
+found-mask select) and ``seine.rank`` (query metadata and the ranker).
+Retrieval's window kernel is ``seine_window_rows``.
 """
 from .export import (dump, parse_prometheus, snapshot, to_prometheus,
                      write_metrics)
@@ -134,14 +146,14 @@ from .log import get_logger, set_level
 from .metrics import (REGISTRY, Counter, Gauge, Histogram, Registry,
                       counter, disabled, enabled, gauge, histogram,
                       set_enabled)
-from .trace import (dump_chrome_trace, enable_chrome_trace, reset_spans,
-                    span, span_stats)
+from .trace import (events, record_events, reset_spans, span,
+                    span_stats)
 
 __all__ = [
     "REGISTRY", "Counter", "Gauge", "Histogram", "Registry",
     "counter", "gauge", "histogram", "enabled", "disabled", "set_enabled",
-    "span", "span_stats", "reset_spans", "enable_chrome_trace",
-    "dump_chrome_trace", "to_prometheus", "parse_prometheus", "snapshot",
+    "span", "span_stats", "reset_spans", "record_events", "events",
+    "to_prometheus", "parse_prometheus", "snapshot",
     "dump", "write_metrics", "get_logger", "set_level", "reset",
 ]
 

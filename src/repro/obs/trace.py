@@ -1,22 +1,20 @@
 """Lightweight tracing spans over the index lifecycle.
 
 ``with span("build.stage2.interact"): ...`` times a region with
-``time.perf_counter()``, nests (a thread-local stack tracks the active
+``time.perf_counter_ns()``, nests (a thread-local stack tracks the active
 span path), and aggregates into per-name stats (count / total / min /
 max / last).  Aggregates export through :mod:`repro.obs.export` as
 ``seine_span_seconds_total{span=...}`` / ``seine_span_count_total`` /
 ``seine_span_last_seconds`` so build-stage timings ride the same
 Prometheus/JSON snapshot as the counters and gauges.
 
-Two optional sinks, both off by default:
-
-* Chrome trace events (``chrome://tracing`` / Perfetto):
-  :func:`enable_chrome_trace` starts collecting complete ("X") events,
-  :func:`dump_chrome_trace` writes the JSON array.
-* ``jax.profiler`` annotations: with ``REPRO_OBS_JAX_TRACE=1`` each span
-  also opens a ``jax.profiler.TraceAnnotation`` so spans line up with
-  device activity in a captured XLA profile.  Import stays lazy — the
-  flag costs nothing when unset.
+Event record, off by default: :func:`record_events` keeps the last
+``capacity`` span exits, each a :class:`SpanEvent` ``(name, t0_ns, t1_ns,
+thread, attrs)``, and :func:`events` reads them.  The times are absolute
+``time.perf_counter_ns()`` readings, so a reader that ties that clock to
+another one (a device trace's, by a program run on the device at a known
+host time) places every span on it.  With the record off a span costs
+its aggregate alone.
 
 A span measures *host wall-clock between enter and exit*: jax dispatch is
 asynchronous, so wrap the ``block_until_ready``/``int(...)`` boundary if
@@ -25,15 +23,12 @@ you want device time included (the build pipeline's per-stage spans do).
 from __future__ import annotations
 
 import contextlib
-import json
-import os
 import threading
 import time
-from typing import Dict, Iterator, List, Optional
+from collections import deque
+from typing import Deque, Dict, Iterator, List, NamedTuple, Optional
 
 from . import metrics as _metrics
-
-_JAX_TRACE = os.environ.get("REPRO_OBS_JAX_TRACE", "") not in ("", "0")
 
 
 class SpanStat:
@@ -59,10 +54,19 @@ class SpanStat:
                 "max_s": self.max_s, "last_s": self.last_s}
 
 
+class SpanEvent(NamedTuple):
+    """One span exit: host ``perf_counter_ns`` at enter and exit, the
+    thread it ran on and the attributes it was given."""
+    name: str
+    t0_ns: int
+    t1_ns: int
+    thread: str
+    attrs: dict
+
+
 _STATS: Dict[str, SpanStat] = {}
 _TLS = threading.local()
-_CHROME: Optional[List[dict]] = None
-_EPOCH = time.perf_counter()
+_EVENTS: Optional[Deque[SpanEvent]] = None
 
 
 def _stack() -> list:
@@ -73,42 +77,33 @@ def _stack() -> list:
 
 
 @contextlib.contextmanager
-def span(name: str, **attrs) -> Iterator[None]:
+def span(name: str, **attrs) -> Iterator[dict]:
     """Time a region and aggregate under ``name``.
 
-    ``attrs`` ride into the Chrome-trace event args (and nowhere else —
-    per-name aggregates stay unlabelled so the hot path never builds a
-    label dict).
+    Yields ``attrs``, which the region may fill in as it learns them (a
+    batch's size once it closes); they ride into the span's event when
+    the record is on, and nowhere else — per-name aggregates stay
+    unlabelled so the hot path never builds a label set.
     """
     if not _metrics.enabled():
-        yield
+        yield attrs
         return
     stack = _stack()
     stack.append(name)
-    ann = None
-    if _JAX_TRACE:
-        import jax
-        ann = jax.profiler.TraceAnnotation(name)
-        ann.__enter__()
-    t0 = time.perf_counter()
+    t0 = time.perf_counter_ns()
     try:
-        yield
+        yield attrs
     finally:
-        dt = time.perf_counter() - t0
-        if ann is not None:
-            ann.__exit__(None, None, None)
+        t1 = time.perf_counter_ns()
         stack.pop()
         stat = _STATS.get(name)
         if stat is None:
             stat = _STATS[name] = SpanStat()
-        stat.add(dt)
-        if _CHROME is not None:
-            _CHROME.append({
-                "name": name, "ph": "X", "pid": os.getpid(),
-                "tid": threading.get_ident(),
-                "ts": (t0 - _EPOCH) * 1e6, "dur": dt * 1e6,
-                "args": {**attrs, "depth": len(stack)},
-            })
+        stat.add((t1 - t0) / 1e9)
+        rec = _EVENTS
+        if rec is not None:
+            rec.append(SpanEvent(name, t0, t1,
+                                 threading.current_thread().name, attrs))
 
 
 def current_span() -> Optional[str]:
@@ -126,26 +121,26 @@ def snapshot() -> dict:
 
 
 def reset_spans() -> None:
+    """Clear the per-name aggregates and any recorded events (the record
+    stays on or off as it was)."""
     _STATS.clear()
+    if _EVENTS is not None:
+        _EVENTS.clear()
 
 
-def enable_chrome_trace() -> None:
-    """Start collecting Chrome-trace events (idempotent)."""
-    global _CHROME
-    if _CHROME is None:
-        _CHROME = []
+def record_events(capacity: Optional[int]) -> None:
+    """Record span events into a fresh ring of the last ``capacity``
+    exits; ``None`` stops recording and drops the record."""
+    global _EVENTS
+    if capacity is None:
+        _EVENTS = None
+        return
+    if int(capacity) <= 0:
+        raise ValueError(f"capacity must be positive, got {capacity}")
+    _EVENTS = deque(maxlen=int(capacity))
 
 
-def disable_chrome_trace() -> None:
-    global _CHROME
-    _CHROME = None
-
-
-def dump_chrome_trace(path: str) -> int:
-    """Write collected events as a Chrome-trace JSON array; returns the
-    event count (0 when collection was never enabled)."""
-    events = _CHROME or []
-    with open(path, "w") as f:
-        json.dump({"traceEvents": events,
-                   "displayTimeUnit": "ms"}, f)
-    return len(events)
+def events() -> List[SpanEvent]:
+    """The recorded span events, oldest first ([] when not recording)."""
+    rec = _EVENTS
+    return [] if rec is None else list(rec)

@@ -9,7 +9,6 @@ serving driver used by launch/serve.py.
 """
 from __future__ import annotations
 
-import os
 import threading
 import time
 from collections import deque
@@ -24,19 +23,6 @@ from .. import obs
 from ..core.builder import IndexBuilder
 from ..core.index import PairLookupIndex, SegmentInvertedIndex
 from ..retrievers import QMeta, get_retriever
-
-
-def _sample_every() -> int:
-    """Sampled lookup stats (found-mask hit rate, shard routing) cost a
-    real device lookup, so they run on every N-th score() call only —
-    N from ``REPRO_OBS_SAMPLE``, default 16 (call 1 always samples so
-    short runs still export the gauges).  Read once per engine at
-    construction: an environ read per score() call is measurable at
-    smoke-scale request rates."""
-    try:
-        return max(int(os.environ.get("REPRO_OBS_SAMPLE", "16")), 1)
-    except ValueError:
-        return 16
 
 
 def make_qmeta(index: PairLookupIndex, query_terms: jnp.ndarray,
@@ -213,26 +199,24 @@ class SeineEngine:
         # literal constants — a copy of a multi-GB index inside the HLO —
         # and a LiveIndex mutates underneath the engine, so its programs
         # must serve whatever snapshot (``_served()``) the call reads.
-        # Compiled code is keyed on shapes, never on array values.
-        self._score = jax.jit(self._score_impl)
+        # Compiled code is keyed on shapes, never on array values.  The
+        # served program's module reads ``jit_seine_score`` in a device
+        # trace; its named scopes (``seine.lookup.*`` in the lookup,
+        # ``seine.rank`` here) attribute its operations.
+        def seine_score(params, index, query_terms, doc_ids):
+            m = index.qd_matrix(query_terms, doc_ids,
+                                impl=self._lookup_impl,
+                                tile=self._lookup_tile)
+            with jax.named_scope("seine.rank"):
+                meta = make_qmeta(index, query_terms, doc_ids)
+                return self.spec.score(params, m, meta, index.functions)
+
+        self._score = jax.jit(seine_score)
         # first-stage retrieval: one jit per static k (jax caches per
         # (k, doc_block) pair); retrieve() trims k > n_docs before jitting
         # so a sweep of oversized ks shares one compiled program
         self._retrieve = jax.jit(self._retrieve_impl,
                                  static_argnames=("k", "doc_block"))
-        # sampled lookup-stats state (mesh-less only; see score()).  The
-        # found-count helper is a SEPARATE lazy jit so sampling can never
-        # perturb the gated ``_score`` program or its compile cache.
-        self._n_calls = 0
-        self._found_fn = None
-        self._t2s_host = None
-        self._t2s_gen = -1
-        self._sample_every = _sample_every()
-        # serve loops flip this on so a sampled call only STAGES its
-        # arguments here; the extra device lookup + blocking int() syncs
-        # then run in flush_lookup_stats(), outside the timed region
-        self.defer_lookup_stats = False
-        self._pending_stats = None
         self._retrieves_counter = obs.counter(
             "seine_engine_retrieves_total", "engine.retrieve calls")
         # per-call registry lookups hoisted to construction: score() is
@@ -240,30 +224,15 @@ class SeineEngine:
         self._scores_counter = obs.counter("seine_engine_scores_total",
                                            "engine.score calls")
         if obs.enabled():
-            from ..core.index import POSTING_TILE
             obs.gauge("seine_index_nnz", "nnz of the served index").set(
                 self.index.nnz)
             obs.gauge("seine_index_nbytes", "bytes of the served index"
                       ).set(self.index.nbytes)
-            if getattr(self.index, "codec", "none") != "none":
-                tile, nmax = int(self.index.codec_tile), self.index.nmax
-            else:
-                tile = int(lookup_tile or POSTING_TILE)
-                nmax = int(self.index.doc_ids.shape[-1])
-            obs.gauge("seine_lookup_tiles_per_shard",
-                      "posting tiles per shard (ceil(Nmax / tile))").set(
-                -(-nmax // tile))
 
     def _served(self):
         """What the jitted programs read: the index, or a LiveIndex's
         current snapshot (a LiveView pytree)."""
         return self.index.view if self._live else self.index
-
-    def _score_impl(self, params, index, query_terms, doc_ids):
-        m = index.qd_matrix(query_terms, doc_ids, impl=self._lookup_impl,
-                            tile=self._lookup_tile)
-        meta = make_qmeta(index, query_terms, doc_ids)
-        return self.spec.score(params, m, meta, index.functions)
 
     def _retrieve_impl(self, params, index, query_terms, k, doc_block):
         """First-stage retrieval over ``index``; for a LiveView the base
@@ -317,18 +286,6 @@ class SeineEngine:
         return self._retrieve(self.params, self._served(), query_terms,
                               k=kk, doc_block=doc_block)
 
-    def flush_lookup_stats(self) -> None:
-        """Run a deferred sampled-stats lookup, if one is staged.
-
-        ``serve_batches``/``serve_retrieval`` call this after recording
-        the request latency so the sampling lookup and its blocking
-        ``int()`` host syncs never land inside the timed span — they
-        used to inflate every ``REPRO_OBS_SAMPLE``-th request's recorded
-        latency (and the p95 at default sampling)."""
-        pending, self._pending_stats = self._pending_stats, None
-        if pending is not None:
-            self._sample_lookup_stats(*pending)
-
     def _place(self, query_terms, doc_ids):
         """Shard candidates over the data axes (fit_spec shrinks/drops axes
         that don't divide the batch — the repo's one divisibility policy)."""
@@ -341,142 +298,20 @@ class SeineEngine:
         return (jax.device_put(query_terms, NamedSharding(self.mesh, P())),
                 jax.device_put(doc_ids, NamedSharding(self.mesh, spec)))
 
-    def _make_found_fn(self):
-        """(index, query_terms (Q,), doc_ids (B,)) -> (found pairs, valid
-        pairs).
-
-        Built on the same ownership logic as the jnp lookup but returning
-        only the found mask — a lazy jit, compiled on the first sampled
-        call, entirely outside the serving ``_score`` program."""
-        index = self.index
-        from ..dist.partition import PartitionedIndex
-        if self._live:
-            # live: the module-level jit takes the view as an argument,
-            # so the sampled stats track mutations like the scorer does
-            from ..dist.live import found_counts
-            return found_counts
-        if not isinstance(index, PartitionedIndex):
-            def impl(index, qt, docs):
-                q = jnp.broadcast_to(qt[None], (docs.shape[0],) + qt.shape)
-                _, found = index.lookup_positions(q, docs)
-                return found.sum(), (q >= 0).sum()
-            return jax.jit(impl)
-
-        if index.codec != "none":
-            # packed layout: no raw doc_ids to vmap over — route per pair
-            # and resolve with the two-level packed bisect (the same
-            # positions the serving lookup lands on, ids decoded at the
-            # probe only)
-            from ..kernels.csr_lookup.ref import _route, packed_bisect
-
-            def impl(index, qt, docs):
-                q = jnp.broadcast_to(qt[None], (docs.shape[0],) + qt.shape)
-                d = jnp.broadcast_to(docs[..., None], q.shape)
-                valid = q >= 0
-                k, lo, hi = _route(q, d, index.term_offsets,
-                                   index.term_to_shard, index.range_lo,
-                                   index.split_term, index.split_doc)
-                pos, v = packed_bisect(index._packed(), index.fences, k,
-                                       lo, hi, d, tile=index.codec_tile,
-                                       spans=index.codec_spans,
-                                       with_value=True)
-                found = (pos < hi) & (v == d) & valid
-                return found.sum(), valid.sum()
-            return jax.jit(impl)
-
-        from ..core.index import csr_lookup_positions
-
-        def impl(index, qt, docs):
-            q = jnp.broadcast_to(qt[None], (docs.shape[0],) + qt.shape)
-            w = q.clip(0)
-            d = jnp.broadcast_to(docs[..., None], q.shape)
-            valid = q >= 0
-            shard_of = index.term_to_shard.at[w].get(mode="clip")
-            range_hi = index.range_hi
-
-            def partial(offsets_k, docs_k, lo_k, hi_k, k):
-                owned = ((shard_of == k) if range_hi is None
-                         else (w >= lo_k) & (w <= hi_k)) & valid
-                local = (w - lo_k).clip(0)
-                _, in_list = csr_lookup_positions(offsets_k, docs_k,
-                                                  local, d)
-                return in_list & owned
-
-            hi = index.range_lo if range_hi is None else range_hi
-            founds = jax.vmap(partial)(
-                index.term_offsets, index.doc_ids, index.range_lo, hi,
-                jnp.arange(index.n_shards,
-                           dtype=index.term_to_shard.dtype))
-            # doc-range sub-shards hold disjoint doc slices of a boundary
-            # term, so at most one sub-shard finds any pair: any == sum
-            return founds.any(axis=0).sum(), valid.sum()
-        return jax.jit(impl)
-
-    def _sample_lookup_stats(self, query_terms, doc_ids) -> None:
-        if self._found_fn is None:
-            self._found_fn = self._make_found_fn()
-            from ..dist.partition import PartitionedIndex
-            if isinstance(self.index, PartitionedIndex) or self._live:
-                self._t2s_host = np.asarray(self.index.term_to_shard)
-        if self._live and self.index.generation != self._t2s_gen:
-            # compaction re-plans the term routing table; refresh the
-            # host copy once per generation
-            self._t2s_host = np.asarray(self.index.term_to_shard)
-            self._t2s_gen = self.index.generation
-        found, total = self._found_fn(self._served(), query_terms,
-                                      doc_ids)
-        found, total = int(found), int(total)
-        obs.counter("seine_lookup_found_total",
-                    "found pairs (sampled)").inc(found)
-        obs.counter("seine_lookup_pairs_sampled_total",
-                    "looked-up pairs (sampled)").inc(total)
-        obs.gauge("seine_lookup_found_ratio",
-                  "found-mask hit rate (sampled)").set(
-            found / max(total, 1))
-        # fused-kernel DMA model: one winning posting tile per valid
-        # (term, doc) cell — `total` IS that cell count for this request
-        obs.gauge("seine_lookup_tile_dmas_per_query",
-                  "posting-tile DMAs per request (sampled)").set(total)
-        qt = np.asarray(query_terms)
-        valid = qt[qt >= 0]
-        n_cand = int(doc_ids.shape[0])
-        pairs = obs.counter("seine_lookup_pairs_total",
-                            "routed pairs per shard (sampled)")
-        if self._t2s_host is not None and valid.size:
-            # past-vocab terms have no routing-table row (the device
-            # lookup clip-routes them and finds nothing) — indexing the
-            # host table with one used to crash the sampled call
-            in_vocab = valid[valid < self._t2s_host.shape[0]]
-            per = np.bincount(self._t2s_host[in_vocab],
-                              minlength=self.index.n_shards)
-            for k, c in enumerate(per):
-                if c:
-                    pairs.inc(int(c) * n_cand, shard=str(k))
-        elif valid.size:
-            pairs.inc(int(valid.size) * n_cand, shard="0")
-
     def score(self, query_terms: jnp.ndarray, doc_ids: jnp.ndarray
               ) -> jnp.ndarray:
-        query_terms = jnp.asarray(query_terms)
-        doc_ids = jnp.asarray(doc_ids)
-        if self.mesh is not None:
-            query_terms, doc_ids = self._place(query_terms, doc_ids)
-        if obs.enabled():
+        """Scores of ``doc_ids`` (B,) for ``query_terms`` (Q,), as a
+        device array the caller waits on.  The ``engine.score`` span
+        times the host's part: argument conversion and the dispatch of
+        the served program, up to the call's return."""
+        with obs.span("engine.score"):
+            query_terms = jnp.asarray(query_terms)
+            doc_ids = jnp.asarray(doc_ids)
+            if self.mesh is not None:
+                query_terms, doc_ids = self._place(query_terms, doc_ids)
             self._scores_counter.inc()
-            self._n_calls += 1
-            # mesh-less only: the helper jit would trace against sharded
-            # arrays and placed-index sampling adds cross-device collects
-            if self.mesh is None and (self._n_calls == 1 or
-                                      self._n_calls % self._sample_every
-                                      == 0):
-                if self.defer_lookup_stats:
-                    # stage only — the serve loop flushes after it stops
-                    # the request timer (see flush_lookup_stats)
-                    self._pending_stats = (query_terms, doc_ids)
-                else:
-                    self._sample_lookup_stats(query_terms, doc_ids)
-        return self._score(self.params, self._served(), query_terms,
-                           doc_ids)
+            return self._score(self.params, self._served(), query_terms,
+                               doc_ids)
 
 
 class NoIndexEngine:
@@ -505,6 +340,16 @@ class NoIndexEngine:
     def score(self, query_terms: jnp.ndarray, doc_ids: jnp.ndarray
               ) -> jnp.ndarray:
         return self._score(self.params, query_terms, doc_ids)
+
+
+def pairs_counter() -> obs.Counter:
+    """``seine_lookup_pairs_total``: the (term, candidate) pairs served,
+    valid query terms x real candidates per request.  The serve loops
+    count it from the host arrays they hold, so it costs no device
+    program and no wait."""
+    return obs.counter("seine_lookup_pairs_total",
+                       "(term, candidate) pairs served: valid query "
+                       "terms x real candidates")
 
 
 @dataclass
@@ -653,48 +498,36 @@ def serve_batches(engine, requests: Sequence[Tuple[np.ndarray, np.ndarray]],
     real_slots = pad_slots = 0
     req_counter = obs.counter("seine_serve_requests_total",
                               "serve_batches requests")
-    # sampled lookup stats cost a device lookup + host syncs; defer them
-    # out of the timed region so they never inflate recorded latency
-    # (see SeineEngine.flush_lookup_stats) — restored on exit so a bare
-    # engine.score() outside a serve loop still samples inline
-    defer = getattr(engine, "flush_lookup_stats", None)
-    prev_defer = getattr(engine, "defer_lookup_stats", False)
-    if defer is not None:
-        engine.defer_lookup_stats = True
-    try:
-        for q, docs in requests:
-            docs = np.asarray(docs)
-            n = docs.shape[0]
-            req_counter.inc()
-            if n == 0:
-                # degenerate request: no candidates to score.
-                # Short-circuit to an empty result instead of padding
-                # (the pad id comes from docs[0], which does not exist)
-                # or paying a device round-trip for a (0,) batch.
-                obs.counter("seine_serve_degenerate_requests_total",
-                            "empty-candidate requests").inc()
-                out.append(np.zeros((0,), np.float32))
-                continue
-            if batch_pad > 0 and n % batch_pad:
-                m = -(-n // batch_pad) * batch_pad
-                docs = np.concatenate(
-                    [docs, np.full(m - n, docs[0], docs.dtype)])
-            real_slots += n
-            pad_slots += docs.shape[0] - n
-            t0 = time.perf_counter()
-            # block on the DEVICE array: np.asarray first would force a
-            # blocking host transfer inside the timed region and
-            # double-count conversion
-            with obs.span("serve.request"):
-                s = jax.block_until_ready(engine.score(jnp.asarray(q),
-                                                       jnp.asarray(docs)))
-            stats.record((time.perf_counter() - t0) * 1e3)
-            if defer is not None:
-                defer()
-            out.append(np.asarray(s)[:n])
-    finally:
-        if defer is not None:
-            engine.defer_lookup_stats = prev_defer
+    pairs = pairs_counter()
+    for q, docs in requests:
+        q, docs = np.asarray(q), np.asarray(docs)
+        n = docs.shape[0]
+        req_counter.inc()
+        if n == 0:
+            # degenerate request: no candidates to score.
+            # Short-circuit to an empty result instead of padding
+            # (the pad id comes from docs[0], which does not exist)
+            # or paying a device round-trip for a (0,) batch.
+            obs.counter("seine_serve_degenerate_requests_total",
+                        "empty-candidate requests").inc()
+            out.append(np.zeros((0,), np.float32))
+            continue
+        pairs.inc(int((q >= 0).sum()) * n)
+        if batch_pad > 0 and n % batch_pad:
+            m = -(-n // batch_pad) * batch_pad
+            docs = np.concatenate(
+                [docs, np.full(m - n, docs[0], docs.dtype)])
+        real_slots += n
+        pad_slots += docs.shape[0] - n
+        t0 = time.perf_counter()
+        # block on the DEVICE array: np.asarray first would force a
+        # blocking host transfer inside the timed region and
+        # double-count conversion
+        with obs.span("serve.request"):
+            s = jax.block_until_ready(engine.score(jnp.asarray(q),
+                                                   jnp.asarray(docs)))
+        stats.record((time.perf_counter() - t0) * 1e3)
+        out.append(np.asarray(s)[:n])
     if obs.enabled() and (real_slots or pad_slots):
         obs.counter("seine_serve_slots_total",
                     "real candidate slots scored").inc(real_slots)

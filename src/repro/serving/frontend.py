@@ -32,13 +32,18 @@ to per-request ``engine.score``):
 Latency accounting: per-request latency is arrival→completion (queue
 wait included — the number a client sees), recorded into a thread-safe
 :class:`~repro.serving.engine.ServeStats` together with the
-time-in-queue split and the queue-depth high-water mark.
+time-in-queue split and the queue-depth high-water mark.  The worker's
+spans split a batch's host time: ``frontend.form`` (batch formation,
+the ``batch_timeout_ms`` wait included), ``engine.score`` per request
+inside ``frontend.batch``, then ``frontend.wait`` and
+``frontend.answer`` per request (see :mod:`repro.obs`).
 
 :func:`run_open_loop` drives a frontend under open-loop Poisson load
 (exponential inter-arrival at ``target_qps``, submission never gated on
-completion) and reports goodput — the fraction of submitted requests
-served within the SLO — which is the serving metric that closed-loop
-min-latency benchmarks cannot see.
+completion), times each request from the instant it was due, and
+reports goodput — the fraction of submitted requests answered within
+the SLO — and the generator's lateness, which closed-loop min-latency
+benchmarks cannot see.
 """
 from __future__ import annotations
 
@@ -50,12 +55,11 @@ from dataclasses import dataclass, field
 from typing import List, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
 from .coalesce import CoalescingScorer
-from .engine import ServeStats
+from .engine import ServeStats, pairs_counter
 from .tile_cache import PostingTileCache
 
 
@@ -148,6 +152,7 @@ class ServingFrontend:
                                         "requests admitted to the queue")
         self._batch_counter = obs.counter("seine_frontend_batches_total",
                                           "batches formed and served")
+        self._pairs = pairs_counter()
         self._slo_counter = obs.counter(
             "seine_serve_slo_misses_total",
             "requests rejected unserved (aged past the SLO in queue)")
@@ -211,22 +216,24 @@ class ServingFrontend:
         if first is _SHUTDOWN:
             return None
         batch = [first]
-        t_close = time.perf_counter() + self.batch_timeout_s
-        while len(batch) < self.max_batch:
-            left = t_close - time.perf_counter()
-            if left <= 0:
-                break
-            try:
-                nxt = self._queue.get(timeout=left)
-            except queue.Empty:
-                break
-            if nxt is _SHUTDOWN:
-                # keep draining: the current batch (and any queued
-                # remainder) still gets served; re-post so the outer
-                # loop terminates once the queue is truly empty
-                self._queue.put(_SHUTDOWN)
-                break
-            batch.append(nxt)
+        with obs.span("frontend.form", size=1) as attrs:
+            t_close = time.perf_counter() + self.batch_timeout_s
+            while len(batch) < self.max_batch:
+                left = t_close - time.perf_counter()
+                if left <= 0:
+                    break
+                try:
+                    nxt = self._queue.get(timeout=left)
+                except queue.Empty:
+                    break
+                if nxt is _SHUTDOWN:
+                    # keep draining: the current batch (and any queued
+                    # remainder) still gets served; re-post so the outer
+                    # loop terminates once the queue is truly empty
+                    self._queue.put(_SHUTDOWN)
+                    break
+                batch.append(nxt)
+            attrs["size"] = len(batch)
         self.stats.note_queue_depth(self._queue.qsize())
         return batch
 
@@ -305,33 +312,59 @@ class ServingFrontend:
                     [docs, np.full(m - n, docs[0], docs.dtype)])
             return docs
 
+        for r in live:
+            self._pairs.inc(int((r.query_terms >= 0).sum())
+                            * r.doc_ids.shape[0])
         with obs.span("frontend.batch"):
             if self.scorer is not None:
                 scores = self.scorer.score_batch(
                     [(r.query_terms, padded(r.doc_ids)) for r in live])
             else:
-                scores = [self.engine.score(jnp.asarray(r.query_terms),
-                                            jnp.asarray(padded(r.doc_ids)))
+                scores = [self.engine.score(r.query_terms,
+                                            padded(r.doc_ids))
                           for r in live]
             for r, w, s in zip(live, waits, scores):
-                s = jax.block_until_ready(s)
-                done_ms = (time.perf_counter() - r.arrival_s) * 1e3
-                self.stats.record(done_ms, queue_ms=w)
-                r.future.set_result(
-                    np.asarray(s)[:r.doc_ids.shape[0]])
+                with obs.span("frontend.wait"):
+                    s = jax.block_until_ready(s)
+                with obs.span("frontend.answer"):
+                    done_ms = (time.perf_counter() - r.arrival_s) * 1e3
+                    self.stats.record(done_ms, queue_ms=w)
+                    r.future.set_result(
+                        np.asarray(s)[:r.doc_ids.shape[0]])
 
 
 @dataclass
 class OpenLoopResult:
-    """Outcome of one open-loop run.  ``goodput`` is the fraction of
-    SUBMITTED requests served within the SLO (rejected requests and
-    served-but-late completions both count against it); with no SLO it
-    degenerates to the served fraction."""
+    """Outcome of one open-loop run.
+
+    ``latency_ms`` holds each request's time from the instant it was due
+    to its answer (NaN where it was rejected), the latency a client sees
+    whether or not the generator kept up; ``late_ms`` how late the
+    generator submitted each request after it was due (a starved
+    generator would otherwise read as a fast server).  ``goodput`` is the
+    fraction of SUBMITTED requests answered within the SLO by that clock
+    (rejected requests and late answers both count against it); with no
+    SLO it degenerates to the answered fraction.  ``stats`` is the
+    frontend's own record, timed from each ``submit``."""
     n_submitted: int
     n_served: int
     n_rejected: int
     goodput: float
     stats: ServeStats
+    latency_ms: np.ndarray
+    late_ms: np.ndarray
+
+    def percentile_ms(self, q: float) -> float:
+        lat = self.latency_ms[np.isfinite(self.latency_ms)]
+        return float(np.percentile(lat, q)) if lat.size else 0.0
+
+    @property
+    def p50_ms(self) -> float:
+        return self.percentile_ms(50.0)
+
+    @property
+    def p95_ms(self) -> float:
+        return self.percentile_ms(95.0)
 
 
 def run_open_loop(frontend: ServingFrontend,
@@ -344,33 +377,54 @@ def run_open_loop(frontend: ServingFrontend,
     never waits on completions — queueing delay under overload shows up
     in the latency tail instead of silently throttling the offered
     load, which is exactly the failure mode closed-loop benchmarks hide.
+    Each request is timed from the instant it was due, not from its
+    submission, so a late generator cannot hide latency either.
     Blocks until every future resolves (the frontend stays open).
     """
     if target_qps <= 0:
         raise ValueError(f"target_qps must be > 0, got {target_qps}")
     rng = np.random.RandomState(seed)
-    gaps = rng.exponential(1.0 / target_qps, size=len(requests))
+    n = len(requests)
+    gaps = rng.exponential(1.0 / target_qps, size=n)
+    due = np.empty(n)
+    submitted = np.empty(n)
+    done = np.full(n, np.nan)
+    # a future wakes its waiters before it runs its callbacks: count
+    # the callbacks in, so every answer time is written before it is read
+    answered = threading.Semaphore(0)
+
+    def on_done(i):
+        def cb(_):
+            done[i] = time.perf_counter()
+            answered.release()
+        return cb
+
     futures = []
     t_next = time.perf_counter()
-    for (q, d), gap in zip(requests, gaps):
+    for i, ((q, d), gap) in enumerate(zip(requests, gaps)):
         t_next += gap
+        due[i] = t_next
         now = time.perf_counter()
         if t_next > now:
             time.sleep(t_next - now)
-        futures.append(frontend.submit(q, d))
-    served = rejected = within = 0
-    for f in futures:
+        submitted[i] = time.perf_counter()
+        f = frontend.submit(q, d)
+        f.add_done_callback(on_done(i))
+        futures.append(f)
+    for _ in futures:
+        answered.acquire()
+    ok = np.zeros(n, bool)
+    rejected = 0
+    for i, f in enumerate(futures):
         try:
             f.result()
-            served += 1
+            ok[i] = True
         except DeadlineExceeded:
             rejected += 1
+    latency = np.where(ok, (done - due) * 1e3, np.nan)
     if frontend.slo_ms is None:
-        goodput = served / max(len(futures), 1)
+        good = int(ok.sum())
     else:
-        lat = np.asarray(frontend.stats.latencies_ms, dtype=np.float64)
-        within = int((lat[-served:] <= frontend.slo_ms).sum()) if served \
-            else 0
-        goodput = within / max(len(futures), 1)
-    return OpenLoopResult(len(futures), served, rejected, goodput,
-                          frontend.stats)
+        good = int((latency[ok] <= frontend.slo_ms).sum())
+    return OpenLoopResult(n, int(ok.sum()), rejected, good / max(n, 1),
+                          frontend.stats, latency, (submitted - due) * 1e3)

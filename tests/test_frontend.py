@@ -329,6 +329,13 @@ class TestServingFrontend:
         assert res.n_served + res.n_rejected == 8
         assert 0.0 <= res.goodput <= 1.0
         assert res.stats is fe.stats
+        # timed from the due instant: never before the submission the
+        # frontend's own record starts from, and lateness is reported
+        assert res.late_ms.shape == (8,) and (res.late_ms >= 0).all()
+        served = np.isfinite(res.latency_ms)
+        assert served.sum() == res.n_served == res.stats.n_requests
+        assert res.p50_ms >= res.stats.p50_ms
+        assert res.p95_ms >= res.stats.p95_ms
 
 
 # ---------------------------------------------------------------------------

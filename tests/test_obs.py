@@ -113,6 +113,66 @@ class TestSpans:
         assert stats["t.outer"].total_s >= stats["t.inner"].total_s
 
 
+class TestEventRecord:
+    @pytest.fixture(autouse=True)
+    def _record_off(self):
+        yield
+        obs.record_events(None)
+
+    def test_off_by_default_and_records_nothing(self):
+        with obs.span("t.unrecorded", size=3):
+            pass
+        assert obs.events() == []
+        assert obs.span_stats()["t.unrecorded"].count == 1  # aggregate
+        obs.record_events(4)
+        obs.record_events(None)                 # off again: dropped
+        with obs.span("t.unrecorded"):
+            pass
+        assert obs.events() == []
+
+    def test_bounded_at_capacity(self):
+        obs.record_events(3)
+        for i in range(5):
+            with obs.span("t.ring", i=i):
+                pass
+        evs = obs.events()
+        assert [e.attrs["i"] for e in evs] == [2, 3, 4]   # the last 3
+        with pytest.raises(ValueError, match="capacity"):
+            obs.record_events(0)
+
+    def test_times_are_perf_counter_ns(self):
+        import threading
+
+        obs.record_events(8)
+        before = time.perf_counter_ns()
+        with obs.span("t.outer"):
+            with obs.span("t.inner") as attrs:
+                time.sleep(0.002)
+                attrs["size"] = 7               # filled in as learnt
+        after = time.perf_counter_ns()
+        inner, outer = obs.events()             # in order of exit
+        assert (inner.name, outer.name) == ("t.inner", "t.outer")
+        assert before <= outer.t0_ns <= inner.t0_ns
+        assert inner.t1_ns - inner.t0_ns >= 2_000_000
+        assert inner.t1_ns <= outer.t1_ns <= after
+        assert inner.thread == threading.current_thread().name
+        assert inner.attrs == {"size": 7} and outer.attrs == {}
+        # the aggregate is the same interval, in seconds
+        st = obs.span_stats()["t.inner"]
+        assert st.last_s == pytest.approx(
+            (inner.t1_ns - inner.t0_ns) / 1e9, abs=1e-9)
+
+    def test_reset_clears_events_and_keeps_recording(self):
+        obs.record_events(8)
+        with obs.span("t.x"):
+            pass
+        obs.reset()
+        assert obs.events() == []
+        with obs.span("t.y"):
+            pass
+        assert [e.name for e in obs.events()] == ["t.y"]   # still on
+
+
 # ---------------------------------------------------------------------------
 # exporters
 # ---------------------------------------------------------------------------
@@ -233,22 +293,63 @@ class TestLifecycleInstrumentation:
         from repro.serving import serve_batches
         engine = _make_engine(seine_world)
         # 30 candidates + pad bucket 16 -> padded to 32, so real pad waste
-        out, stats = serve_batches(engine, _requests(seine_world, n=4,
-                                                     cand=30),
-                                   batch_pad=16)
+        reqs = _requests(seine_world, n=4, cand=30)
+        out, stats = serve_batches(engine, reqs, batch_pad=16)
         assert len(out) == 4
         assert obs.counter("seine_serve_requests_total").get() == 4
         assert obs.counter("seine_engine_scores_total").get() == 4
         assert obs.histogram("seine_serve_latency_ms").cells[()].count == 4
-        # call 1 always samples -> hit-rate stats exist even for short runs
-        sampled = obs.counter("seine_lookup_pairs_sampled_total").get()
-        assert sampled > 0
-        assert 0.0 <= obs.gauge("seine_lookup_found_ratio").get() <= 1.0
-        assert obs.counter("seine_lookup_found_total").get() <= sampled
-        assert obs.counter("seine_lookup_pairs_total").get(shard="0") > 0
+        assert obs.span_stats()["engine.score"].count == 4
+        # every request counted exactly, from the host arrays: valid
+        # terms x real candidates (pads excluded), nothing sampled
+        want = sum(int((np.asarray(q) >= 0).sum()) * len(d)
+                   for q, d in reqs)
+        assert want > 0
+        assert obs.counter("seine_lookup_pairs_total").get() == want
+        assert obs.REGISTRY.get("seine_lookup_found_ratio") is None
         assert obs.gauge("seine_index_nnz").get() == \
             seine_world["index"].nnz
         assert obs.gauge("seine_serve_pad_waste_ratio").get() > 0.0
+
+    def test_frontend_batch_events_on_the_worker(self, seine_world):
+        """One batch of the frontend's worker, recorded: its formation,
+        then per request the engine's dispatch, the wait and the answer,
+        nested in ``frontend.batch``, all on the ``seine-frontend``
+        thread."""
+        from repro.serving import ServingFrontend
+        engine = _make_engine(seine_world)
+        reqs = _requests(seine_world, n=2, cand=16)
+        with ServingFrontend(engine, max_batch=2, batch_timeout_ms=10_000,
+                             coalesce=False) as fe:
+            fe.submit(*reqs[0]).result(timeout=300)      # warm: compile
+            t_rec = time.perf_counter_ns()
+            obs.record_events(64)
+            try:
+                futs = [fe.submit(q, d) for q, d in reqs]
+                for f in futs:
+                    f.result(timeout=300)
+            finally:
+                fe.close()              # the batch's spans have all exited
+                evs = obs.events()
+                obs.record_events(None)
+        # the warm-up batch may still be closing its spans at t_rec
+        worker = [e for e in evs if e.thread == "seine-frontend"
+                  and e.t0_ns >= t_rec]
+        assert [e.name for e in worker] == [
+            "frontend.form", "engine.score", "engine.score",
+            "frontend.wait", "frontend.answer", "frontend.wait",
+            "frontend.answer", "frontend.batch"]
+        form, batch = worker[0], worker[-1]
+        assert form.attrs == {"size": 2}
+        assert form.t1_ns <= batch.t0_ns
+        for a, b in zip(worker[1:-1], worker[2:-1]):
+            assert a.t1_ns <= b.t0_ns                    # in sequence
+        assert all(batch.t0_ns <= e.t0_ns and e.t1_ns <= batch.t1_ns
+                   for e in worker[1:-1])
+        # counted from the host arrays the worker holds
+        assert obs.counter("seine_lookup_pairs_total").get() == sum(
+            int((np.asarray(q) >= 0).sum()) * len(d)
+            for q, d in [reqs[0]] + reqs)
 
     def test_serve_batches_empty_request_short_circuits(self, seine_world):
         from repro.serving import serve_batches
@@ -282,6 +383,39 @@ class TestLifecycleInstrumentation:
         assert obs.counter("seine_straggler_flagged_total").get() == 1
         assert obs.gauge(
             "seine_straggler_median_step_seconds").get() == 1.0
+
+
+class TestServedProgramNames:
+    def test_interpret_lookup_holds_scopes_and_kernel_names(
+            self, seine_world):
+        """The served program is ``jit_seine_score``; its lowered text
+        (the kernel path, in the Pallas interpreter) names its four parts
+        and the position kernel, and retrieval names its window kernel —
+        the names a device trace attributes operations by."""
+        import re
+
+        from repro.dist.sharding import partition_index
+
+        def scopes(lowered):
+            # every name-stack component of the program's op locations
+            text = lowered.as_text(debug_info=True)
+            return text, {part for loc in re.findall(r'loc\("([^"]*)"', text)
+                          for part in loc.split("/")}
+
+        pidx = partition_index(seine_world["index"], 2)
+        engine = _make_engine(dict(seine_world, index=pidx))
+        engine._lookup_impl = "interpret"          # the kernel path on CPU
+        q = jax.numpy.asarray(seine_world["queries"][0])
+        d = jax.numpy.arange(16, dtype=jax.numpy.int32)
+        text, names = scopes(engine._score.lower(engine.params,
+                                                 engine.index, q, d))
+        assert "module @jit_seine_score" in text
+        assert {"seine.lookup.ids", "seine.lookup.positions",
+                "seine.lookup.gather", "seine.rank",
+                "seine_csr_positions"} <= names
+        _, names = scopes(engine._retrieve.lower(
+            engine.params, engine.index, q, k=4, doc_block=None))
+        assert "seine_window_rows" in names
 
 
 # ---------------------------------------------------------------------------
@@ -375,8 +509,11 @@ class TestServeDriverMetricsOut:
         nnz = fams["seine_shard_nnz"]
         assert {k for (_, k), in nnz} == {"0", "1"}
         assert all(v > 0 for v in nnz.values())
-        # found-mask hit rate
-        assert 0.0 <= fams["seine_lookup_found_ratio"][()] <= 1.0
+        # (term, candidate) pairs served, counted on the host: 2 passes
+        # x 4 requests x 32 candidates x the queries' valid terms
+        pairs = fams["seine_lookup_pairs_total"][()]
+        assert pairs > 0 and pairs % (2 * 32) == 0
+        assert "seine_lookup_found_ratio" not in fams
         # serve latency histogram (2 serve_batches passes x 4 requests)
         assert fams["seine_serve_latency_ms_count"][()] == 8.0
         assert fams["seine_serve_latency_ms_bucket"][
@@ -405,30 +542,24 @@ class TestServeDriverMetricsOut:
 
 class _SynthEngine:
     """Deterministic stand-in for SeineEngine in the overhead A/B: the
-    same per-score obs surface (cached counter, call counter, sampling
-    modulo — the sample period pinned past the window), but a fixed numpy
-    workload instead of an XLA dispatch.  Async-dispatch jitter on a
-    loaded machine is 10-20x the instrumentation cost, so an A/B over the
-    real engine measures scheduler noise, not obs; pure host compute
-    makes min-of-N converge to the actual delta."""
+    same per-score obs surface (the ``engine.score`` span and the cached
+    call counter), but a fixed numpy workload instead of an XLA dispatch.
+    Async-dispatch jitter on a loaded machine is 10-20x the
+    instrumentation cost, so an A/B over the real engine measures
+    scheduler noise, not obs; pure host compute makes min-of-N converge
+    to the actual delta."""
 
     def __init__(self, work_elems: int = 16_384):
         self._x = np.random.RandomState(0).rand(work_elems) \
             .astype(np.float32)
         self._scores_counter = obs.counter("seine_engine_scores_total",
                                            "engine.score calls")
-        self._n_calls = 0
-        self._sample_every = 1 << 30
 
     def score(self, q, docs):
-        if obs.enabled():
+        with obs.span("engine.score"):
             self._scores_counter.inc()
-            self._n_calls += 1
-            if self._n_calls == 1 or \
-                    self._n_calls % self._sample_every == 0:
-                pass                        # sampling window never hit
-        np.sort(self._x)                    # the "request": ~100s of us
-        return np.zeros(np.asarray(docs).shape[0], np.float32)
+            np.sort(self._x)                # the "request": ~100s of us
+            return np.zeros(np.asarray(docs).shape[0], np.float32)
 
 
 class TestOverhead:
@@ -437,12 +568,9 @@ class TestOverhead:
 
         from repro.serving import serve_batches
         # Bounds the ALWAYS-ON instrumentation on the serve loop: request
-        # counter, serve.request span, latency histogram, engine score
-        # counter + sampling check.  The sampled found-mask stats cost a
-        # real device lookup by design and amortise through their own
-        # REPRO_OBS_SAMPLE knob, so the synthetic engine pins the period
-        # past the measured window rather than letting a deliberately-
-        # paced probe masquerade as hot-path overhead.
+        # and pair counters, serve.request and engine.score spans,
+        # latency histogram, engine score counter.  No metric runs a
+        # device program, so nothing is left out of the A/B.
         #
         # Estimator: shared CI machines drift multiplicatively on ~100ms
         # scales, so raw min-of-N across arms measures load, not obs.

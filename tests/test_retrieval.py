@@ -9,13 +9,13 @@ bitwise on the single-block default; multi-block scans may drift ~1 ulp
 (XLA fuses the scorer into the loop body), which cannot reorder docs
 whose scores differ by more than that noise.
 """
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.data.synth_corpus import build_zipfian_index
 from repro.dist.sharding import partition_index
 from repro.kernels.csr_lookup import csr_retrieve_block
@@ -291,35 +291,31 @@ class TestServingPathFixes:
                                  np.arange(4))], batch_pad=-1)
 
     def test_sampling_off_timed_path(self, small_index):
-        """A/B: an artificially slow stats sampler must not show up in
-        the recorded request latency — serve_batches defers it past the
-        timer — while a bare score() (no serve loop) still pays it
-        inline.  Deterministic: the sleep dwarfs any real serve cost."""
+        """The timed path runs the served program and nothing else: one
+        dispatch of it per request (no second lookup program, no host
+        sync for stats), while the pair counter comes from the request's
+        host arrays — valid terms x real candidates, pads excluded."""
         eng = self._engine(small_index)
-        eng._sample_every = 1               # sample EVERY call
         q = np.asarray(QUERY, np.int32)
-        docs = np.arange(16)
-        serve_batches(eng, [(q, docs)])     # warm: compile outside timing
-
-        sleep_s = 0.2
+        docs = np.arange(13)
+        serve_batches(eng, [(q, docs)], batch_pad=8)   # warm: compile
+        served = eng._score
         calls = []
-        orig = eng._sample_lookup_stats
 
-        def slow_sample(qt, d):
-            time.sleep(sleep_s)
+        def counted(*a):
             calls.append(1)
-            orig(qt, d)
+            return served(*a)
 
-        eng._sample_lookup_stats = slow_sample
-        _, stats = serve_batches(eng, [(q, docs)] * 3)
-        assert len(calls) == 3              # sampling DID run (deferred)
-        assert max(stats.latencies_ms) < sleep_s * 1e3
-        assert eng.defer_lookup_stats is False   # flag restored
-
-        # control arm: outside a serve loop the sampler runs inline
-        t0 = time.perf_counter()
+        eng._score = counted
+        pairs = obs.counter("seine_lookup_pairs_total")
+        before = pairs.get()
+        out, stats = serve_batches(eng, [(q, docs)] * 3, batch_pad=8)
+        assert len(calls) == 3 and stats.n_requests == 3
+        assert pairs.get() - before == 3 * int((q >= 0).sum()) * 13
+        assert all(o.shape == (13,) for o in out)
+        # a bare score() counts no pairs: only the serve loops do
         jax.block_until_ready(eng.score(jnp.asarray(q), jnp.asarray(docs)))
-        assert (time.perf_counter() - t0) >= sleep_s
+        assert pairs.get() - before == 3 * int((q >= 0).sum()) * 13
 
     def test_quantile_snapshot_equivalence(self):
         """Cached-snapshot percentiles == sorting per access, and the
@@ -348,10 +344,15 @@ class TestServingPathFixes:
         assert s.percentile_ms(100.0) == 99.0
 
     def test_sampled_stats_survive_past_vocab_terms(self, small_index):
-        """Regression: a partitioned engine's sampled routing stats used
-        to crash indexing the host routing table with past-vocab terms
-        (they have no table row; the device lookup clip-routes them)."""
+        """Regression: a partitioned engine's routing stats once crashed
+        indexing the host routing table with past-vocab terms (they
+        have no table row; the device lookup clip-routes them).  The
+        pair count now never touches that table: past-vocab terms are
+        valid slots and count like any other."""
         eng = self._engine(small_index, partition="term", n_shards=4)
-        eng._sample_every = 1
-        q = jnp.asarray([3, 99, 1000, -1], dtype=jnp.int32)
-        jax.block_until_ready(eng.score(q, jnp.arange(8)))
+        q = np.asarray([3, 99, 1000, -1], np.int32)
+        pairs = obs.counter("seine_lookup_pairs_total")
+        before = pairs.get()
+        out, _ = serve_batches(eng, [(q, np.arange(8))])
+        assert np.isfinite(out[0]).all()
+        assert pairs.get() - before == 3 * 8
