@@ -101,7 +101,10 @@ class PairLookupIndex(Protocol):
     term-range :class:`~repro.dist.partition.PartitionedIndex` — that can
     materialise M_{q,d} rows (zeros for absent pairs, the sigma=0
     semantics) plus the per-doc/per-term stats QMeta needs is servable;
-    retrievers never learn which one produced M.
+    retrievers never learn which one produced M.  ``cols``, a static
+    tuple of function indices, narrows M's last axis to those functions,
+    bit for bit the full M's ``[..., cols]``; the engine passes the
+    functions its ranker reads.
     """
     idf: jnp.ndarray           # (|v|,)
     doc_len: jnp.ndarray       # (n_docs,)
@@ -119,11 +122,13 @@ class PairLookupIndex(Protocol):
 
     def fn_index(self, name: str) -> int: ...
 
-    def lookup_pairs(self, term_ids: jnp.ndarray, doc_ids: jnp.ndarray
+    def lookup_pairs(self, term_ids: jnp.ndarray, doc_ids: jnp.ndarray,
+                     *, cols: Optional[Tuple[int, ...]] = None
                      ) -> jnp.ndarray: ...
 
     def qd_matrix(self, query_terms: jnp.ndarray, doc_ids: jnp.ndarray,
-                  *, impl: str = None, tile: Optional[int] = None
+                  *, impl: str = None, tile: Optional[int] = None,
+                  cols: Optional[Tuple[int, ...]] = None
                   ) -> jnp.ndarray: ...
 
     def retrieve_topk(self, query_terms: jnp.ndarray, k: int,
@@ -183,16 +188,24 @@ class SegmentInvertedIndex:
                                             w, d)
         return pos, in_list & (term_ids >= 0)
 
-    def lookup_pairs(self, term_ids: jnp.ndarray, doc_ids: jnp.ndarray
+    def lookup_pairs(self, term_ids: jnp.ndarray, doc_ids: jnp.ndarray,
+                     *, cols: Optional[Tuple[int, ...]] = None
                      ) -> jnp.ndarray:
-        """(..., Q) term ids x (...,) doc ids -> (..., Q, n_b, n_f).
-        Missing pairs -> zeros."""
+        """(..., Q) term ids x (...,) doc ids -> (..., Q, n_b, n_f), or
+        (..., Q, n_b, len(cols)) with ``cols``.  Missing pairs -> zeros."""
         pos, found = self.lookup_positions(term_ids, doc_ids)
-        vals = self.values.at[pos].get(mode="clip")
+        if cols is None:
+            vals = self.values.at[pos].get(mode="clip")
+        else:
+            n_b = self.values.shape[1]
+            vals = self.values.at[
+                pos[..., None, None], jnp.arange(n_b)[:, None],
+                jnp.asarray(cols, jnp.int32)[None, :]].get(mode="clip")
         return vals * found[..., None, None]
 
     def qd_matrix(self, query_terms: jnp.ndarray, doc_ids: jnp.ndarray,
-                  *, impl: str = None, tile: Optional[int] = None
+                  *, impl: str = None, tile: Optional[int] = None,
+                  cols: Optional[Tuple[int, ...]] = None
                   ) -> jnp.ndarray:
         """Stack rows for the query terms (Eq. 4).
 
@@ -213,6 +226,7 @@ class SegmentInvertedIndex:
         ``POSTING_TILE``); the jnp path ignores it (no tiling there).
         Every impl x tile is held bitwise-equal to
         ``csr_lookup_positions`` by tests/test_kernels.py::TestCsrLookup.
+        ``cols`` narrows M to those function columns on every impl.
         """
         if impl not in (None, "fused", "jnp", "interpret"):
             raise ValueError(f"unknown lookup impl {impl!r}; supported: "
@@ -220,13 +234,14 @@ class SegmentInvertedIndex:
         if impl == "jnp":
             q = jnp.broadcast_to(query_terms[None],
                                  (doc_ids.shape[0],) + query_terms.shape)
-            return self.lookup_pairs(q, doc_ids)
+            return self.lookup_pairs(q, doc_ids, cols=cols)
         from ..kernels.csr_lookup import csr_lookup
         return csr_lookup(
             self.term_offsets[None], self.doc_ids[None], self.values[None],
             None, None, query_terms, doc_ids,
             fences=None if self.fences is None else self.fences[None],
-            tile=tile, interpret=True if impl == "interpret" else None)
+            tile=tile, interpret=True if impl == "interpret" else None,
+            cols=cols)
 
     def retrieve_topk(self, query_terms: jnp.ndarray, k: int,
                       score_block_fn, *, doc_block: Optional[int] = None,

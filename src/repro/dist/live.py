@@ -118,29 +118,32 @@ class LiveView:
     # -- lookups ------------------------------------------------------------
 
     def lookup_pairs(self, term_ids: jnp.ndarray, doc_ids: jnp.ndarray,
-                     *, impl: str = None) -> jnp.ndarray:
+                     *, impl: str = None, cols=None) -> jnp.ndarray:
         """(..., Q) term ids x (...,) doc ids -> (..., Q, n_b, n_f).
 
         ``base.lookup_pairs + delta.lookup_pairs`` with the tombstone
         mask folded into both found-masks; exclusive doc-space ownership
-        makes the sum an exclusive write per cell (exact)."""
+        makes the sum an exclusive write per cell (exact).  ``cols``
+        narrows both to those function columns."""
         v = self.base.lookup_pairs(term_ids, doc_ids, impl=impl,
-                                   alive=self.alive)
+                                   alive=self.alive, cols=cols)
         if self.delta is not None:
             v = v + self.delta.lookup_pairs(term_ids, doc_ids, impl=impl,
-                                            alive=self.alive)
+                                            alive=self.alive, cols=cols)
         return v
 
     def qd_matrix(self, query_terms: jnp.ndarray, doc_ids: jnp.ndarray,
-                  *, impl: str = None, tile: Optional[int] = None
-                  ) -> jnp.ndarray:
+                  *, impl: str = None, tile: Optional[int] = None,
+                  cols=None) -> jnp.ndarray:
         """query_terms (Q,) x doc_ids (B,) -> M (B, Q, n_b, n_f), the
-        serving cartesian over the live ``base + delta - tombstones``."""
+        serving cartesian over the live ``base + delta - tombstones``;
+        ``cols`` narrows base and delta to those function columns."""
         m = self.base.qd_matrix(query_terms, doc_ids, impl=impl,
-                                tile=tile, alive=self.alive)
+                                tile=tile, alive=self.alive, cols=cols)
         if self.delta is not None:
             m = m + self.delta.qd_matrix(query_terms, doc_ids, impl=impl,
-                                         tile=tile, alive=self.alive)
+                                         tile=tile, alive=self.alive,
+                                         cols=cols)
         return m
 
     def retrieve_topk(self, query_terms: jnp.ndarray, k: int,
@@ -323,16 +326,18 @@ class LiveIndex:
         """The current immutable serve snapshot (atomic read)."""
         return self._view
 
-    def lookup_pairs(self, term_ids, doc_ids, *, impl=None):
+    def lookup_pairs(self, term_ids, doc_ids, *, impl=None, cols=None):
         """See :meth:`LiveView.lookup_pairs` (delegates to a snapshot)."""
         return self._view.lookup_pairs(jnp.asarray(term_ids),
-                                       jnp.asarray(doc_ids), impl=impl)
+                                       jnp.asarray(doc_ids), impl=impl,
+                                       cols=cols)
 
-    def qd_matrix(self, query_terms, doc_ids, *, impl=None, tile=None):
+    def qd_matrix(self, query_terms, doc_ids, *, impl=None, tile=None,
+                  cols=None):
         """See :meth:`LiveView.qd_matrix` (delegates to a snapshot)."""
         return self._view.qd_matrix(jnp.asarray(query_terms),
                                     jnp.asarray(doc_ids), impl=impl,
-                                    tile=tile)
+                                    tile=tile, cols=cols)
 
     def retrieve_topk(self, query_terms, k, score_block_fn, *,
                       doc_block=None, impl=None, tile=None):

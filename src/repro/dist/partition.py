@@ -228,7 +228,8 @@ class PartitionedIndex:
     # -- lookups (Eq. 4, term-partitioned) ----------------------------------
 
     def lookup_pairs(self, term_ids: jnp.ndarray, doc_ids: jnp.ndarray,
-                     *, impl: str = None, alive=None) -> jnp.ndarray:
+                     *, impl: str = None, alive=None,
+                     cols=None) -> jnp.ndarray:
         """(..., Q) term ids x (...,) doc ids -> (..., Q, n_b, n_f).
 
         Route each term to its owning shard, resolve shard-locally (zeros
@@ -250,6 +251,10 @@ class PartitionedIndex:
         ``alive`` (n_docs,) bool tombstones deleted docs: their pairs
         resolve to exact zeros, identical to an index rebuilt without
         them (:class:`~repro.dist.live.LiveIndex` passes it).
+
+        ``cols`` (static tuple of function indices) gathers only those
+        columns -> (..., Q, n_b, len(cols)), bit for bit the full rows'
+        ``[..., cols]``, on every impl.
         """
         if impl not in (None, "fused", "jnp"):
             raise ValueError(f"unknown lookup impl {impl!r}; supported: "
@@ -263,12 +268,12 @@ class PartitionedIndex:
                     self._serve_values, self.value_scale,
                     self.term_to_shard, self.range_lo, term_ids, doc_ids,
                     self.split_term, self.split_doc, tile=self.codec_tile,
-                    spans=self.codec_spans, alive=alive)
+                    spans=self.codec_spans, alive=alive, cols=cols)
             from ..kernels.csr_lookup import lookup_pairs_ref
             return lookup_pairs_ref(
                 self.term_offsets, self.doc_ids, self.values,
                 self.term_to_shard, self.range_lo, term_ids, doc_ids,
-                self.split_term, self.split_doc, alive=alive)
+                self.split_term, self.split_doc, alive=alive, cols=cols)
         w = term_ids.clip(0)
         d = jnp.broadcast_to(doc_ids[..., None], term_ids.shape)
         shard_of = self.term_to_shard.at[w].get(mode="clip")
@@ -295,12 +300,13 @@ class PartitionedIndex:
         pos, found = jax.vmap(partial)(
             self.term_offsets, self.doc_ids, self.range_lo, hi, ks)
         vals = gather_rows(self.values,
-                           ks.reshape((-1,) + (1,) * (pos.ndim - 1)), pos)
+                           ks.reshape((-1,) + (1,) * (pos.ndim - 1)), pos,
+                           cols)
         return (vals * found[..., None, None]).sum(axis=0)
 
     def qd_matrix(self, query_terms: jnp.ndarray, doc_ids: jnp.ndarray,
                   *, impl: str = None, tile: Optional[int] = None,
-                  alive=None) -> jnp.ndarray:
+                  alive=None, cols=None) -> jnp.ndarray:
         """query_terms (Q,), doc_ids (B,) -> M_{q,d} (B, Q, n_b, n_f).
 
         The serving hot path.  ``impl=None``/``"fused"`` dispatches to
@@ -308,7 +314,8 @@ class PartitionedIndex:
         jnp lowering on CPU); ``"jnp"`` keeps the SPMD partial-sum
         composition for mesh-placed serving; ``"interpret"`` forces the
         Pallas interpreter (the oracle-parity sweep).  ``tile`` overrides
-        the kernel's posting-tile width (jnp path ignores it).
+        the kernel's posting-tile width (jnp path ignores it).  ``cols``
+        narrows M to those function columns, as in :meth:`lookup_pairs`.
         """
         if impl not in (None, "fused", "jnp", "interpret"):
             raise ValueError(f"unknown lookup impl {impl!r}; supported: "
@@ -317,7 +324,8 @@ class PartitionedIndex:
         if impl == "jnp":
             q = jnp.broadcast_to(query_terms[None],
                                  (doc_ids.shape[0],) + query_terms.shape)
-            return self.lookup_pairs(q, doc_ids, impl="jnp", alive=alive)
+            return self.lookup_pairs(q, doc_ids, impl="jnp", alive=alive,
+                                     cols=cols)
         self._check_codec_tile(tile)
         from ..kernels.csr_lookup import csr_lookup
         return csr_lookup(
@@ -331,7 +339,7 @@ class PartitionedIndex:
             packed=self._packed() if self.codec != "none" else None,
             value_scale=self.value_scale,
             max_tile_words=self.max_tile_words,
-            codec_spans=self.codec_spans, alive=alive)
+            codec_spans=self.codec_spans, alive=alive, cols=cols)
 
     def retrieve_topk(self, query_terms: jnp.ndarray, k: int,
                       score_block_fn, *, doc_block: Optional[int] = None,

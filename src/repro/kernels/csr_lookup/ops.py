@@ -89,9 +89,10 @@ def _word_rows(packed, mw):
 def _lookup_found(term_offsets, term_to_shard, range_lo, split_term,
                   split_doc, query_terms, doc_targets, fences, rows,
                   values, value_scale, *, tile, stride, packed_meta=None,
-                  max_tile_words=0, interpret=False):
+                  max_tile_words=0, interpret=False, cols=None):
     """Kernel-path lookup: route, resolve positions in the Pallas kernel,
-    gather the found rows from ``values`` -> M (B, Q, n_b, n_f).
+    gather the ``cols`` columns of the found rows from ``values`` -> M
+    (B, Q, n_b, n_f), or (B, Q, n_b, len(cols)).
 
     The gather and the masking select are the jnp refs' expressions
     (``values[k, pos]`` where found, exact zeros elsewhere; q8 dequant
@@ -115,7 +116,7 @@ def _lookup_found(term_offsets, term_to_shard, range_lo, split_term,
         found = (flat >= 0)[..., None, None]
         f = jnp.maximum(flat, 0)
         kk = f // n
-        vals = gather_rows(values, kk, f - kk * n)
+        vals = gather_rows(values, kk, f - kk * n, cols)
         if value_scale is not None:
             sc = _lane_scale(value_scale, range_lo, kk,
                              query_terms[None, :])
@@ -126,7 +127,7 @@ def _lookup_found(term_offsets, term_to_shard, range_lo, split_term,
 
 @partial(jax.jit,
          static_argnames=("tile", "interpret", "codec", "max_tile_words",
-                          "codec_spans"))
+                          "codec_spans", "cols"))
 def csr_lookup(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
                values: jnp.ndarray, term_to_shard, range_lo,
                query_terms: jnp.ndarray, doc_targets: jnp.ndarray,
@@ -140,7 +141,8 @@ def csr_lookup(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
                value_scale: jnp.ndarray | None = None,
                max_tile_words: int = 0,
                codec_spans: tuple = (0, 0),
-               alive: jnp.ndarray | None = None) -> jnp.ndarray:
+               alive: jnp.ndarray | None = None,
+               cols: tuple | None = None) -> jnp.ndarray:
     """Fused lookup–merge: query_terms (Q,) x doc_targets (B,) over a
     K-stacked shard CSR -> M_{q,d} (B, Q, n_b, n_f); zeros for absent
     pairs, OOV / past-vocab terms and out-of-range doc ids.
@@ -170,6 +172,10 @@ def csr_lookup(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
     folds into the found mask; on the kernel paths the output rows are
     masked per candidate doc — mathematically identical, since
     not-found rows are already exact zeros and the mask is per doc.
+
+    ``cols`` (static tuple of function indices) gathers only those value
+    columns -> (B, Q, n_b, len(cols)), bit for bit ``M[..., cols]``; the
+    serving engine passes the functions its ranker reads.
     """
     from ...core.index import POSTING_TILE, build_fences, fence_count
 
@@ -181,7 +187,7 @@ def csr_lookup(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
                 term_offsets, packed, fences, values, value_scale,
                 term_to_shard, range_lo, query_terms, doc_targets,
                 split_term, split_doc, tile=t, spans=tuple(codec_spans),
-                alive=alive)
+                alive=alive, cols=cols)
         mw = int(max_tile_words)
         with jax.named_scope("seine.lookup.ids"):
             rows = _word_rows(packed, mw)
@@ -190,12 +196,13 @@ def csr_lookup(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
             query_terms, doc_targets, fences, rows,
             values, value_scale, tile=t, stride=packed[0].shape[1],
             packed_meta=(packed[2], packed[3]), max_tile_words=mw,
-            interpret=bool(interpret))
+            interpret=bool(interpret), cols=cols)
         return _mask_dead_rows(out, alive, doc_targets)
     if interpret is None and jax.default_backend() != "tpu":
         return csr_lookup_ref(term_offsets, doc_ids, values, term_to_shard,
                               range_lo, query_terms, doc_targets,
-                              split_term, split_doc, alive=alive)
+                              split_term, split_doc, alive=alive,
+                              cols=cols)
     n_fence = fence_count(doc_ids.shape[1], t)
     with jax.named_scope("seine.lookup.ids"):
         # stored fences are spaced at the build-time POSTING_TILE —
@@ -208,7 +215,7 @@ def csr_lookup(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
     out = _lookup_found(
         term_offsets, term_to_shard, range_lo, split_term, split_doc,
         query_terms, doc_targets, fences, rows, values, None, tile=t,
-        stride=n_fence * t, interpret=bool(interpret))
+        stride=n_fence * t, interpret=bool(interpret), cols=cols)
     return _mask_dead_rows(out, alive, doc_targets)
 
 

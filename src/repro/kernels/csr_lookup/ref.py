@@ -56,13 +56,17 @@ def bisect_steps(n: int) -> int:
     return max(int(n).bit_length(), 1)
 
 
-GATHER_CHUNK = 512   # rows per element gather (see gather_rows)
+GATHER_CHUNK = 512   # full rows' worth of elements per gather (gather_rows)
 
 
-def gather_rows(values: jnp.ndarray, k: jnp.ndarray,
-                pos: jnp.ndarray) -> jnp.ndarray:
+def gather_rows(values: jnp.ndarray, k: jnp.ndarray, pos: jnp.ndarray,
+                cols=None) -> jnp.ndarray:
     """``values[k, pos]`` for ``values (K, N, n_b, n_f)`` and broadcastable
     int32 ``k``/``pos`` (clipped into range) -> ``(..., n_b, n_f)``.
+
+    ``cols``, a static tuple of function indices, gathers only those
+    columns: ``(..., n_b, len(cols))``, equal bit for bit to
+    ``gather_rows(values, k, pos)[..., cols]``; ``None`` gathers all.
 
     Spelled as a gather of single elements from the ``(K, n_b, n_f, N)``
     view, not of whole ``(n_b, n_f)`` rows.  A TPU stores ``values`` in a
@@ -70,13 +74,18 @@ def gather_rows(values: jnp.ndarray, k: jnp.ndarray,
     that layout in place, while a row gather first relayouts the whole
     operand — a per-call copy of an index that fills most of HBM.  The
     element gather pads each element's index vector to a 128-lane row
-    (~92 KB of indices per gathered row), so rows are gathered
-    ``GATHER_CHUNK`` at a time.  The moved axis is a layout change only:
-    on every backend the result is the row gather's, bit for bit.
+    (~92 KB of indices per full gathered row), so a chunk holds at most
+    ``GATHER_CHUNK * n_b * n_f`` elements: ``GATHER_CHUNK`` full rows, or
+    ``n_f / len(cols)`` times as many narrowed ones.  The moved axis is a
+    layout change only: on every backend the result is the row gather's,
+    bit for bit.
     """
     k, pos = jnp.broadcast_arrays(k, pos)
     shape = pos.shape
     n_b, n_f = values.shape[2], values.shape[3]
+    fs = jnp.arange(n_f) if cols is None else jnp.asarray(cols, jnp.int32)
+    n_c = fs.shape[0]
+    chunk = GATHER_CHUNK * n_f // n_c
 
     def rows(kc, pc):
         # the view is taken inside the chunk loop: a view hoisted out of
@@ -84,17 +93,17 @@ def gather_rows(values: jnp.ndarray, k: jnp.ndarray,
         # — a full copy of values
         return jnp.moveaxis(values, 1, 3).at[
             kc[:, None, None], jnp.arange(n_b)[:, None],
-            jnp.arange(n_f)[None, :], pc[:, None, None]].get(mode="clip")
+            fs[None, :], pc[:, None, None]].get(mode="clip")
 
     kf, pf = k.reshape(-1), pos.reshape(-1)
     n = kf.shape[0]
-    if n <= GATHER_CHUNK:
-        return rows(kf, pf).reshape(shape + (n_b, n_f))
-    c = -(-n // GATHER_CHUNK)
-    kf = jnp.pad(kf, (0, c * GATHER_CHUNK - n)).reshape(c, GATHER_CHUNK)
-    pf = jnp.pad(pf, (0, c * GATHER_CHUNK - n)).reshape(c, GATHER_CHUNK)
+    if n <= chunk:
+        return rows(kf, pf).reshape(shape + (n_b, n_c))
+    c = -(-n // chunk)
+    kf = jnp.pad(kf, (0, c * chunk - n)).reshape(c, chunk)
+    pf = jnp.pad(pf, (0, c * chunk - n)).reshape(c, chunk)
     out = jax.lax.map(lambda a: rows(*a), (kf, pf))
-    return out.reshape((-1, n_b, n_f))[:n].reshape(shape + (n_b, n_f))
+    return out.reshape((-1, n_b, n_c))[:n].reshape(shape + (n_b, n_c))
 
 
 def _alive_at(alive, d):
@@ -212,11 +221,12 @@ def lookup_pairs_ref(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
                      values: jnp.ndarray, term_to_shard, range_lo,
                      term_ids: jnp.ndarray, doc_targets: jnp.ndarray,
                      split_term=None, split_doc=None,
-                     alive=None) -> jnp.ndarray:
+                     alive=None, cols=None) -> jnp.ndarray:
     """Generic-batch routed lookup: term_ids (..., Q) x doc_targets
     broadcastable (...,) -> (..., Q, n_b, n_f), zeros for absent pairs.
     ``alive`` (n_docs,) bool, when given, tombstones docs: pairs whose
-    doc is dead resolve to the same exact zeros as absent pairs."""
+    doc is dead resolve to the same exact zeros as absent pairs.
+    ``cols`` gathers only those function columns (:func:`gather_rows`)."""
     from ...core.index import _bisect
 
     K, N = doc_ids.shape
@@ -229,7 +239,7 @@ def lookup_pairs_ref(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
     in_list = (pos < base + hi) & (flat.at[pos].get(mode="clip") == d)
     if alive is not None:
         in_list = in_list & _alive_at(alive, d)
-    vals = gather_rows(values, k, pos - base)
+    vals = gather_rows(values, k, pos - base, cols)
     # select, not multiply-by-mask: XLA fuses the select into the gather
     # consumer, a bool-mask product materialises a second full-size pass
     # (~15% of the lookup on CPU); absent pairs are +0.0 either way
@@ -368,9 +378,9 @@ def csr_lookup_ref(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
                    values: jnp.ndarray, term_to_shard, range_lo,
                    query_terms: jnp.ndarray, doc_targets: jnp.ndarray,
                    split_term=None, split_doc=None,
-                   alive=None) -> jnp.ndarray:
+                   alive=None, cols=None) -> jnp.ndarray:
     """The serving cartesian: query_terms (Q,) x doc_targets (B,) ->
-    M_{q,d} (B, Q, n_b, n_f).
+    M_{q,d} (B, Q, n_b, n_f), or ``(B, Q, n_b, len(cols))`` with ``cols``.
 
     Without sub-shards, routing runs once on the (Q,) terms and
     broadcasts over candidates — cheaper than the single-CSR path's
@@ -393,7 +403,7 @@ def csr_lookup_ref(term_offsets: jnp.ndarray, doc_ids: jnp.ndarray,
     in_list = (pos < hi_f) & (flat.at[pos].get(mode="clip") == d)
     if alive is not None:
         in_list = in_list & _alive_at(alive, d)
-    vals = gather_rows(values, k, pos - k * N)
+    vals = gather_rows(values, k, pos - k * N, cols)
     # select over multiply-by-mask: see lookup_pairs_ref
     return jnp.where(in_list[..., None, None], vals, 0.0)
 
@@ -526,10 +536,12 @@ def _lane_scale(value_scale, range_lo, k, term_ids):
 
 def _lookup_packed(term_offsets, packed, fences, values, value_scale,
                    term_to_shard, range_lo, split_term, split_doc,
-                   term_ids, d, *, tile: int, spans=(0, 0), alive=None):
+                   term_ids, d, *, tile: int, spans=(0, 0), alive=None,
+                   cols=None):
     """Shared body of the packed lookup refs: route, two-level packed
-    bisect, decode-at-found check, values gather (+ optional dequant).
-    ``term_ids``/``d`` already broadcast to the common pair shape."""
+    bisect, decode-at-found check, values gather of the ``cols`` columns
+    (+ optional dequant).  ``term_ids``/``d`` already broadcast to the
+    common pair shape."""
     k_n, nmax = values.shape[0], values.shape[1]
     k, lo, hi = _route(term_ids, d, term_offsets, term_to_shard, range_lo,
                        split_term, split_doc)
@@ -549,10 +561,10 @@ def _lookup_packed(term_offsets, packed, fences, values, value_scale,
         scale = _lane_scale(value_scale, range_lo, k, term_ids)
         kk, ix, sc, fd = jax.lax.optimization_barrier(
             (k, pos, scale, found))
-        vals = gather_rows(values, kk, ix).astype(jnp.float32)
+        vals = gather_rows(values, kk, ix, cols).astype(jnp.float32)
         return jnp.where(fd[..., None, None], vals * sc[..., None, None], 0.0)
     kk, ix, fd = jax.lax.optimization_barrier((k, pos, found))
-    vals = gather_rows(values, kk, ix)
+    vals = gather_rows(values, kk, ix, cols)
     # select over multiply-by-mask: see lookup_pairs_ref
     return jnp.where(fd[..., None, None], vals, 0.0)
 
@@ -561,7 +573,7 @@ def lookup_pairs_packed_ref(term_offsets, packed, fences, values,
                             value_scale, term_to_shard, range_lo,
                             term_ids, doc_targets, split_term=None,
                             split_doc=None, *, tile: int, spans=(0, 0),
-                            alive=None):
+                            alive=None, cols=None):
     """Packed-codec :func:`lookup_pairs_ref`: term_ids (..., Q) x
     doc_targets broadcastable (...,) -> (..., Q, n_b, n_f).  Ids decode
     losslessly, so found masks/positions — and with f32 ``values`` the
@@ -571,14 +583,14 @@ def lookup_pairs_packed_ref(term_offsets, packed, fences, values,
     return _lookup_packed(term_offsets, packed, fences, values,
                           value_scale, term_to_shard, range_lo,
                           split_term, split_doc, term_ids, d, tile=tile,
-                          spans=spans, alive=alive)
+                          spans=spans, alive=alive, cols=cols)
 
 
 def csr_lookup_packed_ref(term_offsets, packed, fences, values,
                           value_scale, term_to_shard, range_lo,
                           query_terms, doc_targets, split_term=None,
                           split_doc=None, *, tile: int, spans=(0, 0),
-                          alive=None):
+                          alive=None, cols=None):
     """Packed-codec :func:`csr_lookup_ref`: query_terms (Q,) x
     doc_targets (B,) -> M (B, Q, n_b, n_f)."""
     shape = (doc_targets.shape[0], query_terms.shape[0])    # (B, Q)
@@ -587,7 +599,7 @@ def csr_lookup_packed_ref(term_offsets, packed, fences, values,
     return _lookup_packed(term_offsets, packed, fences, values,
                           value_scale, term_to_shard, range_lo,
                           split_term, split_doc, w, d, tile=tile,
-                          spans=spans, alive=alive)
+                          spans=spans, alive=alive, cols=cols)
 
 
 def retrieve_block_packed_ref(term_offsets, packed, fences, values,

@@ -64,6 +64,8 @@ seine_codec_bytes_saved               gauge     posting bytes codec removed
 seine_codec_shrink                    gauge     raw / packed payload bytes
 seine_index_nnz                       gauge     nnz of the served index
 seine_index_nbytes                    gauge     bytes of the served index
+seine_engine_lookup_functions         gauge     functions score gathers per
+                                                (term, doc) pair
 seine_engine_scores_total             counter   engine.score calls
 seine_engine_retrieves_total          counter   engine.retrieve calls
 seine_retrieve_requests_total         counter   serve_retrieval requests

@@ -29,6 +29,15 @@ class QMeta:
 
 @dataclass(frozen=True)
 class RetrieverSpec:
+    """A registered scorer.
+
+    ``needs`` is a contract, not a note: ``SeineEngine.score`` gathers
+    only these atomic functions from the index and passes ``score`` an M
+    whose last axis holds just them, with the matching narrowed
+    ``functions`` tuple.  A scorer must read M only through
+    ``fidx(functions, name)`` and only for names in ``needs``; reading
+    any other function raises at trace time.
+    """
     name: str
     init: Callable[..., Any]          # (key, n_b, functions) -> params
     score: Callable[..., jnp.ndarray]  # (params, M, meta, functions) -> (B,)

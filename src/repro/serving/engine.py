@@ -83,6 +83,12 @@ class SeineEngine:
     gated in CI).  A pre-built PartitionedIndex carries its own codec and
     is served as-is; packed layouts are mesh-less only and pin the
     lookup tile to their build-time ``codec_tile``.
+
+    ``score`` gathers only the functions the retriever declares in
+    ``spec.needs``, in ``index.functions`` order, and hands the scorer M
+    with the matching narrowed ``functions`` tuple; a retriever that
+    needs every function gets the full lookup.  Retrieval scans the
+    full M.
     """
 
     def __init__(self, index: PairLookupIndex, retriever: str,
@@ -194,6 +200,16 @@ class SeineEngine:
         # (partial-sum merge -> all-reduce over the model axis)
         self._lookup_impl = "jnp" if mesh is not None else "fused"
         self._lookup_tile = lookup_tile
+        # the served lookup gathers only the functions the ranker reads;
+        # the scorer indexes M through fidx(functions, name), so it gets
+        # the narrowed tuple with it (a function its needs omit then
+        # raises in fidx at trace time); cols=None, the full lookup, when
+        # it reads every function
+        functions = tuple(index.functions)
+        cols = tuple(i for i, f in enumerate(functions)
+                     if f in self.spec.needs)
+        self._cols = None if len(cols) == len(functions) else cols
+        self._functions = tuple(functions[i] for i in cols)
         # the jitted programs take the index as a pytree ARGUMENT, never
         # as a closure: jit embeds closed-over arrays in the program as
         # literal constants — a copy of a multi-GB index inside the HLO —
@@ -206,10 +222,10 @@ class SeineEngine:
         def seine_score(params, index, query_terms, doc_ids):
             m = index.qd_matrix(query_terms, doc_ids,
                                 impl=self._lookup_impl,
-                                tile=self._lookup_tile)
+                                tile=self._lookup_tile, cols=self._cols)
             with jax.named_scope("seine.rank"):
                 meta = make_qmeta(index, query_terms, doc_ids)
-                return self.spec.score(params, m, meta, index.functions)
+                return self.spec.score(params, m, meta, self._functions)
 
         self._score = jax.jit(seine_score)
         # first-stage retrieval: one jit per static k (jax caches per
@@ -228,6 +244,9 @@ class SeineEngine:
                 self.index.nnz)
             obs.gauge("seine_index_nbytes", "bytes of the served index"
                       ).set(self.index.nbytes)
+            obs.gauge("seine_engine_lookup_functions",
+                      "functions gathered per (term, doc) pair by score"
+                      ).set(len(self._functions))
 
     def _served(self):
         """What the jitted programs read: the index, or a LiveIndex's

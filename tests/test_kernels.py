@@ -15,6 +15,7 @@ import pytest
 from repro.kernels import (csr_lookup, embed_bag, embed_bag_ref,
                            flash_attention, flash_attn_ref, knrm_pool,
                            knrm_pool_ref, seg_interact, seg_interact_ref)
+from repro.retrievers import all_retrievers, get_retriever
 
 
 class TestSegInteract:
@@ -289,6 +290,114 @@ class TestCsrLookup:
             np.testing.assert_array_equal(
                 np.asarray(_bisect(arr, lo, hi, t, bisect_steps(n))),
                 np.asarray(_bisect(arr, lo, hi, t, 32)), err_msg=f"n={n}")
+
+
+def _all_functions_spec(read):
+    """A scorer over the ``read`` functions that declares every function
+    of the index as needed (the case the narrowing leaves alone)."""
+    from repro.core.interactions import FUNCTION_NAMES
+    from repro.retrievers import RetrieverSpec, fidx
+
+    def score(params, m, meta, functions):
+        return sum(m[..., fidx(functions, f)].sum((1, 2)) for f in read)
+
+    return RetrieverSpec(name="all_functions", init=lambda *a: {},
+                         score=score, needs=FUNCTION_NAMES)
+
+
+class TestFunctionColumns:
+    """The served lookup gathers only the functions its ranker reads
+    (``RetrieverSpec.needs``): every narrowed gather equals the full
+    gather's columns, and every engine score the full M's, bit for bit."""
+
+    @pytest.mark.parametrize("cols", ((3,), (0, 1, 4), (0, 1, 2, 3)))
+    @pytest.mark.parametrize("n", (7, 5000))
+    @pytest.mark.parametrize("dtype", (np.float32, np.int8))
+    def test_gather_rows_cols_bitwise(self, dtype, n, cols):
+        """One chunk (n = 7) and the chunk loop (n = 5,000: more than a
+        chunk of full and of narrowed rows alike)."""
+        from repro.kernels.csr_lookup.ref import GATHER_CHUNK, gather_rows
+        rng = np.random.RandomState(n + len(cols))
+        values = jnp.asarray((rng.randn(2, 300, 4, 9) * 50).astype(dtype))
+        k = jnp.asarray(rng.randint(0, 2, n).astype(np.int32))
+        pos = jnp.asarray(rng.randint(-5, 310, n).astype(np.int32))
+        full = np.asarray(gather_rows(values, k, pos))
+        got = np.asarray(gather_rows(values, k, pos, cols))
+        assert got.shape == (n, 4, len(cols)) and got.dtype == dtype
+        np.testing.assert_array_equal(got, full[..., list(cols)])
+        loops = "scan" in str(jax.make_jaxpr(
+            lambda v, a, b: gather_rows(v, a, b, cols))(values, k, pos))
+        assert loops == (n > GATHER_CHUNK * 9 // len(cols))
+
+    @pytest.mark.parametrize("retriever", all_retrievers())
+    def test_engine_narrowed_scores_bitwise(self, seine_world, retriever):
+        """``SeineEngine.score`` (M narrowed to ``needs``) == the spec's
+        score over the full M with the full ``functions``, K in {1, 4}."""
+        from repro.dist.sharding import partition_index
+        from repro.serving import SeineEngine
+        from repro.serving.engine import make_qmeta
+        w = seine_world
+        idx = w["index"]
+        spec = get_retriever(retriever)
+        params = spec.init(jax.random.key(0), idx.n_b, idx.functions)
+
+        @jax.jit
+        def full_m_score(params, p, q, d):
+            return spec.score(params, p.qd_matrix(q, d),
+                              make_qmeta(p, q, d), p.functions)
+
+        docs = jnp.arange(16)
+        for k in (1, 4):
+            p = partition_index(idx, k)
+            eng = SeineEngine(p, retriever, params)
+            assert eng._functions == tuple(
+                f for f in idx.functions if f in spec.needs)
+            for i, qq in enumerate(w["queries"][:2]):
+                q = jnp.asarray(qq)
+                np.testing.assert_array_equal(
+                    np.asarray(eng.score(q, docs)),
+                    np.asarray(full_m_score(params, p, q, docs)),
+                    err_msg=f"{retriever} K={k} query {i}")
+
+    @pytest.mark.parametrize("retriever,gathered",
+                             (("knrm", 1), ("deeptilebars", 3),
+                              ("all_functions", 9)))
+    def test_lookup_functions_gauge(self, seine_world, monkeypatch,
+                                    retriever, gathered):
+        """``seine_engine_lookup_functions`` reads the functions gathered
+        per pair; a ranker that needs all nine gets the full lookup."""
+        from repro import obs
+        from repro.retrievers import base
+        from repro.serving import SeineEngine
+        monkeypatch.setitem(base._REGISTRY, "all_functions",
+                            _all_functions_spec(("tf", "cosine")))
+        idx = seine_world["index"]
+        spec = get_retriever(retriever)
+        params = spec.init(jax.random.key(0), idx.n_b, idx.functions)
+        assert obs.enabled()
+        eng = SeineEngine(idx, retriever, params)
+        assert obs.gauge("seine_engine_lookup_functions").get() == gathered
+        assert (eng._cols is None) == (gathered == len(idx.functions))
+        q = jnp.asarray(seine_world["queries"][0])
+        assert np.isfinite(np.asarray(eng.score(q, jnp.arange(8)))).all()
+
+    def test_scorer_reading_outside_needs_raises(self, seine_world,
+                                                 monkeypatch):
+        """A scorer that reads a function missing from its ``needs``
+        raises at trace time; it never reads zeros in its place."""
+        from repro.retrievers import RetrieverSpec, base, fidx
+        from repro.serving import SeineEngine
+
+        def score(params, m, meta, functions):
+            return m[..., fidx(functions, "tf")].sum((1, 2))
+
+        monkeypatch.setitem(base._REGISTRY, "reads_tf", RetrieverSpec(
+            name="reads_tf", init=lambda *a: {}, score=score,
+            needs=("cosine",)))
+        eng = SeineEngine(seine_world["index"], "reads_tf", {})
+        with pytest.raises(ValueError, match="not in tuple"):
+            eng.score(jnp.asarray(seine_world["queries"][0]),
+                      jnp.arange(8))
 
 
 class TestKnrmPool:

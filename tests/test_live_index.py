@@ -96,6 +96,14 @@ def live2(seine_world):
     return _mk_live(seine_world, 2)
 
 
+@pytest.fixture(scope="module")
+def live2_dead(seine_world):
+    """A delta plus three tombstones (base ids and a delta id); READ-ONLY."""
+    live = _mk_live(seine_world, 2)
+    live.delete([1, 3, live.n_docs - 2])
+    return live
+
+
 # ---------------------------------------------------------------------------
 # insert-only parity: live == from-scratch rebuild, bit for bit
 # ---------------------------------------------------------------------------
@@ -288,6 +296,43 @@ class TestDeletes:
         ref[np.isin(np.asarray(d), dead)] = 0.0
         np.testing.assert_allclose(np.asarray(live.lookup_pairs(t, d)),
                                    ref, rtol=0, atol=0)
+
+    @pytest.mark.parametrize("impl", ("fused", "interpret", "jnp"))
+    def test_qd_matrix_cols_bitwise(self, seine_world, live2_dead, impl):
+        """Base and delta narrow alike: ``cols=c`` == the full M's
+        ``[..., c]`` bit for bit, dead docs included; the engine's score
+        over the narrowed M == the spec's over the full one."""
+        live = live2_dead
+        assert live.delta_nnz > 0 and live.tombstones == 3
+        q = jnp.asarray(QUERY, jnp.int32)
+        docs = jnp.arange(live.n_docs, dtype=jnp.int32)
+        full = np.asarray(live.qd_matrix(q, docs, impl=impl))
+        t, d = _pairs(live.n_docs, seine_world["vocab"].size)
+        full_pairs = (None if impl == "interpret" else
+                      np.asarray(live.lookup_pairs(t, d, impl=impl)))
+        for cols in ((3,), (0, 1, 4)):
+            np.testing.assert_array_equal(
+                np.asarray(live.qd_matrix(q, docs, impl=impl, cols=cols)),
+                full[..., list(cols)], err_msg=f"qd_matrix cols={cols}")
+            if full_pairs is not None:
+                np.testing.assert_array_equal(
+                    np.asarray(live.lookup_pairs(t, d, impl=impl,
+                                                 cols=cols)),
+                    full_pairs[..., list(cols)],
+                    err_msg=f"lookup_pairs cols={cols}")
+        spec, params = _retriever("deeptilebars", live)
+        eng = SeineEngine(live, "deeptilebars", params)
+        eng._lookup_impl = impl
+        assert len(eng._functions) == 3
+
+        @jax.jit
+        def full_m_score(params, v, q, d):
+            return spec.score(params, v.qd_matrix(q, d, impl=impl),
+                              make_qmeta(v, q, d), v.functions)
+
+        np.testing.assert_array_equal(
+            np.asarray(eng.score(q, docs)),
+            np.asarray(full_m_score(params, live.view, q, docs)))
 
     def test_retrieve_excludes_dead(self, seine_world):
         live = _mk_live(seine_world, 2)

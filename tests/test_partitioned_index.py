@@ -313,6 +313,39 @@ class TestOracleParity:
 
         prop()
 
+    @pytest.mark.parametrize("impl,k,codec", [
+        (impl, k, codec) for impl in ("fused", "interpret", "jnp")
+        for k in (0, 1, 4) for codec in ("none", "packed", "packed-q8")
+        if codec == "none" or (k and impl != "jnp")])
+    def test_qd_matrix_cols_bitwise(self, seine_world, impl, k, codec):
+        """``qd_matrix(..., cols=c)`` == ``qd_matrix(...)[..., c]`` bit for
+        bit on every lookup path (K = 0: the single-CSR index), and
+        ``lookup_pairs`` likewise where the path has one."""
+        w = seine_world
+        idx = w["index"]
+        p = idx if k == 0 else partition_index(idx, k, codec=codec)
+        rng = np.random.RandomState(k)
+        docs = jnp.asarray(_adversarial_docs(idx, rng))
+        q = jnp.asarray(_adversarial_queries(w, rng, n=1)[0])
+        terms = jnp.asarray(
+            rng.randint(-1, idx.vocab_size, (3, 5)).astype(np.int32))
+        pair_docs = jnp.asarray(
+            rng.randint(0, idx.n_docs, (3,)).astype(np.int32))
+        full = np.asarray(p.qd_matrix(q, docs, impl=impl))
+        pairs = {} if k == 0 else {"impl": impl}
+        full_pairs = (None if impl == "interpret" else
+                      np.asarray(p.lookup_pairs(terms, pair_docs, **pairs)))
+        for cols in ((3,), (0, 1, 4), (0, 1, 2, 3)):
+            np.testing.assert_array_equal(
+                np.asarray(p.qd_matrix(q, docs, impl=impl, cols=cols)),
+                full[..., list(cols)], err_msg=f"qd_matrix cols={cols}")
+            if full_pairs is not None:
+                np.testing.assert_array_equal(
+                    np.asarray(p.lookup_pairs(terms, pair_docs, cols=cols,
+                                              **pairs)),
+                    full_pairs[..., list(cols)],
+                    err_msg=f"lookup_pairs cols={cols}")
+
     def test_lookup_pairs_batched_shapes(self, seine_world):
         """lookup_pairs parity holds under extra batch dims too."""
         idx = seine_world["index"]

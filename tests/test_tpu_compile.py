@@ -14,6 +14,7 @@ The topology is described inside a module fixture, never while a module
 is imported: only one process may load the TPU library at a time.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -104,6 +105,29 @@ def test_lookup_compiles_for_v5e(one_chip, k, codec):
         packed=idx["packed"], value_scale=idx["value_scale"],
         max_tile_words=MAX_TILE_WORDS if idx["packed"] else 0)
     _check_compiled(lookup.compile(), idx["values"])
+
+
+def test_narrowed_lookup_compiles_for_v5e(one_chip):
+    """The served lookup of a ranker that reads one function (KNRM's
+    ``cosine``, ``cols=(3,)``): ``values`` is still read in place, and
+    every element gather takes 20 x 1 elements per pair; no gather of
+    whole 20 x 9 rows is left."""
+    idx, s = _index_shapes(one_chip, 1, "none")
+    compiled = ops.csr_lookup.lower(
+        idx["term_offsets"], idx["doc_ids"], idx["values"], None, None,
+        s((Q,)), s((B,)), fences=idx["fences"], interpret=False,
+        cols=(3,)).compile()
+    _check_compiled(compiled, idx["values"])
+    text = compiled.as_text()
+    gathers = [tuple(int(x) for x in dims.split(","))
+               for dims in re.findall(r"= f32\[([\d,]+)\]\S* gather\(",
+                                      text)]
+    assert gathers, "no values gather in the program"
+    for shape in gathers:
+        assert shape[-1] == N_B or shape[-2:] == (N_B, 1), shape
+    full_rows = {dims for dims in re.findall(r"\[([\d,]+)\]", text)
+                 if dims.endswith(f",{N_B},{N_F}")}
+    assert full_rows == {f"1,{NNZ},{N_B},{N_F}"}, full_rows   # values only
 
 
 @pytest.mark.parametrize("codec", ["none", "packed-q8"])
