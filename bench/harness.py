@@ -5,17 +5,28 @@ Whatever belongs to one configuration, traffic mix or per-layer metric is a
 file of its own, found by the name ``BENCHMARK.json`` gives it:
 
     bench/configs/<config>.json     a deployment (its ``file`` entry)
+    bench/providers/<provider>.py   its embedding provider (the
+                                    configuration's ``"provider"``): the
+                                    provider's weights, the program's
+                                    provider object, the plain reference
     bench/rankers/<ranker>.py       the plain reference of its ranker
     bench/traffic/<traffic>.json    a mix, read by ``bench/traffic.py``
     bench/metrics/<metric>.py       one per-layer reader: ``read(run)``
 
-From the program the benchmark takes the system under test (the index
-builder, ``SeineEngine`` and ``ServingFrontend``), its spans and counters.
+A configuration's ``"engine"`` (default ``"seine"``) chooses what serves:
+``"seine"`` builds the index and serves ``SeineEngine``; ``"noindex"``
+builds no index and serves ``NoIndexEngine``, which computes M per request
+from the documents' tokens.
+
+From the program the benchmark takes the system under test (the provider,
+the index builder, the engine and ``ServingFrontend``), its spans and
+counters.
 """
 from __future__ import annotations
 
 import concurrent.futures
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -33,7 +44,9 @@ from . import traffic
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "bench")
 PROBE_KEY = "bench_lookup_probe"   # the probe's module name in the trace
+INTERACTION_PROBE_KEY = "bench_interaction_probe"   # noindex's probe
 MARK_KEY = "bench_clock_mark"      # the clock mark's module name
+ENGINES = ("seine", "noindex")
 
 
 class NoChip(RuntimeError):
@@ -81,6 +94,7 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     w = by_name[name]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
     config = _load_json(os.path.join(root, conf["file"]))
+    check_seams(config, root)
     mix = _load_json(os.path.join(root, "bench", "traffic",
                                   w["traffic"] + ".json"))
     e2e = [m for m in bench["end_to_end"] if _for_cell(m, name, set())]
@@ -89,12 +103,33 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     return Cell(name, int(w["chips"]), config, mix, e2e, per_layer)
 
 
+def check_seams(config: dict, root: str = ROOT) -> None:
+    """Refuse a configuration whose provider has no file under
+    ``bench/providers/`` or whose engine is not one of :data:`ENGINES`,
+    before any device work."""
+    name = config.get("name")
+    have = sorted(f[:-3] for f in os.listdir(os.path.join(
+        root, "bench", "providers")) if f.endswith(".py"))
+    if config.get("provider") not in have:
+        raise ValueError(f"configuration {name!r}: no provider "
+                         f"{config.get('provider')!r} under bench/providers/ "
+                         f"(have {have})")
+    engine = config.get("engine", "seine")
+    if engine not in ENGINES:
+        raise ValueError(f"configuration {name!r}: unknown engine "
+                         f"{engine!r} (have {list(ENGINES)})")
+
+
 def reader(metric: str):
     return load_module(os.path.join(BENCH, "metrics", metric + ".py")).read
 
 
 def ranker(name: str):
     return load_module(os.path.join(BENCH, "rankers", name + ".py"))
+
+
+def provider(name: str):
+    return load_module(os.path.join(BENCH, "providers", name + ".py"))
 
 
 # -- the device ------------------------------------------------------------
@@ -183,26 +218,43 @@ def make_weights(config: dict, seed: int):
     return make(jax.random.key(key_seed(seed, 11)))
 
 
+@dataclasses.dataclass
+class DocStats:
+    """What ``make_qmeta`` reads of an index, and nothing else: the
+    ``index`` a ``NoIndexEngine`` is given, which holds no values."""
+    idf: object                 # (|v|,) float32
+    doc_len: object             # (n_docs,) float32
+    seg_len: object             # (n_docs, n_b) float32
+    functions: tuple
+
+    @property
+    def avg_doc_len(self):
+        import jax.numpy as jnp
+        return jnp.mean(self.doc_len)
+
+
 class Deployment:
-    """One configuration at one seed: corpus, weights, index, engine."""
+    """One configuration at one seed: corpus, weights, provider, engine
+    (with the index it serves from, for ``"seine"``)."""
 
     def __init__(self, config: dict, seed: int, log=None):
         import jax.numpy as jnp
 
         from repro.configs.base import SeineConfig
-        from repro.core import HashProvider, IndexBuilder
+        from repro.core import IndexBuilder
         from repro.core.vocab import Vocabulary
-        from repro.serving import SeineEngine
 
         log = log or (lambda *a, **k: None)
         self.config, self.seed = config, seed
+        self.engine_kind = config.get("engine", "seine")
         t = time.perf_counter()
         self.corpus = c = corpus_mod.generate(config, seed)
         log("corpus", s=time.perf_counter() - t,
             tokens=int((c.tokens >= 0).sum()))
         t = time.perf_counter()
         v = c.vocab_size
-        self.table_seed = key_seed(seed, 10)
+        prov = provider(config["provider"])
+        self.provider_weights = prov.weights(config, seed, v)
         self.ip, self.params = make_weights(config, seed)
         self.functions = tuple(config["functions"])
         cfg = SeineConfig(
@@ -215,17 +267,35 @@ class Deployment:
         ident = np.arange(v, dtype=np.int32)
         vocab = Vocabulary(raw_to_slot=ident, slot_to_raw=ident,
                            idf=c.idf, n_docs=c.tokens.shape[0])
-        provider = HashProvider(v, config["embed_dim"], seed=self.table_seed)
-        builder = IndexBuilder(cfg, vocab, provider, ip=self.ip,
-                               functions=self.functions)
-        self.index = builder.build_partitioned(
-            c.tokens, c.segs, config["shards"],
-            batch_size=config["build_batch"])
-        self.max_uniq = min(c.tokens.shape[1], 512)   # the builder's default
-        self.engine = SeineEngine(self.index, config["ranker"], self.params)
-        self.nnz = int(self.index.nnz)
-        log("build", s=time.perf_counter() - t, nnz=self.nnz)
-        self._jnp = jnp
+        builder = IndexBuilder(cfg, vocab,
+                               prov.program(config, self.provider_weights, v),
+                               ip=self.ip, functions=self.functions)
+        if self.engine_kind == "seine":
+            from repro.serving import SeineEngine
+
+            self.index = builder.build_partitioned(
+                c.tokens, c.segs, config["shards"],
+                batch_size=config["build_batch"])
+            self.max_uniq = min(c.tokens.shape[1], 512)  # builder's default
+            self.engine = SeineEngine(self.index, config["ranker"],
+                                      self.params)
+            self.nnz = int(self.index.nnz)
+        else:
+            from repro.core.build_pipeline import compute_doc_seg_lengths
+            from repro.serving import NoIndexEngine
+
+            self.index = None
+            doc_len, seg_len = compute_doc_seg_lengths(
+                c.tokens, c.segs, config["n_segments"])
+            stats = DocStats(jnp.asarray(c.idf), jnp.asarray(doc_len),
+                             jnp.asarray(seg_len), self.functions)
+            # M on the fly holds every term a document has: no cap
+            self.max_uniq = c.tokens.shape[1]
+            self.engine = NoIndexEngine(builder, stats, c.tokens, c.segs,
+                                        config["ranker"], self.params)
+            self.nnz = 0
+        log("build", s=time.perf_counter() - t, engine=self.engine_kind,
+            nnz=self.nnz)
 
     def frontend(self, mix: dict):
         from repro.serving import ServingFrontend
@@ -238,15 +308,15 @@ class Deployment:
             coalesce=fe["coalesce"], cache_tiles=fe["cache_tiles"])
 
     def release(self) -> None:
-        """Drop the index and engine: the reference runs without them."""
+        """Drop the index and engine (with the tokens a ``NoIndexEngine``
+        holds on the device): the reference runs without them."""
         self.engine = self.index = None
         gc.collect()
 
 
 def warm_up(dep: Deployment, mix: dict, seed: int) -> None:
     """Serve requests of the mix's own shapes through a frontend of the
-    cell's settings, so every program the window runs is compiled (and the
-    sampled lookup stats' program with it)."""
+    cell's settings, so every program the window runs is compiled."""
     n = 2 * dep.config["frontend"]["max_batch"]
     reqs = traffic.schedule(mix, dep.corpus, seed, n / mix["rate_rps"],
                             stream=4)
@@ -443,18 +513,33 @@ def padded(docs: np.ndarray, pad: int) -> np.ndarray:
 
 
 def probe(dep: Deployment, mix: dict, reqs: list, idx: List[int]) -> list:
-    """M of each checked request from the benchmark's own jit of
-    ``PartitionedIndex.qd_matrix`` at its served (padded) shape.  Its module
-    in the trace is named after ``PROBE_KEY``; its device time gives the
-    lookup's per-request time and roofline share."""
+    """M of each checked request at its served (padded) shape, from what
+    the engine computes it with.
+
+    ``"seine"``: the benchmark's own jit of ``PartitionedIndex.qd_matrix``;
+    its module in the trace is named after ``PROBE_KEY``, and its device
+    time gives the lookup's per-request time and roofline share.
+    ``"noindex"``: the engine's on-the-fly function (its builder's
+    ``make_qd_fn``) over the tokens it holds, under
+    ``INTERACTION_PROBE_KEY``, so no lookup metric reads its time."""
     import jax
 
-    def bench_lookup_probe(index, q, d):
-        return index.qd_matrix(q, d)
+    if dep.engine_kind == "seine":
+        def bench_lookup_probe(index, q, d):
+            return index.qd_matrix(q, d)
 
-    run = jax.jit(bench_lookup_probe)
-    outs = [run(dep.index, reqs[i].terms,
-                padded(reqs[i].docs, mix["batch_pad"])) for i in idx]
+        run = functools.partial(jax.jit(bench_lookup_probe), dep.index)
+    else:
+        eng = dep.engine
+        qd_fn = eng.builder.make_qd_fn()
+
+        def bench_interaction_probe(tokens, segs, q, d):
+            return qd_fn(q, tokens[d], segs[d])
+
+        run = functools.partial(jax.jit(bench_interaction_probe),
+                                eng.tokens, eng.segs)
+    outs = [run(reqs[i].terms, padded(reqs[i].docs, mix["batch_pad"]))
+            for i in idx]
     return [np.asarray(m)[:len(reqs[i].docs)] for m, i in zip(outs, idx)]
 
 
@@ -466,10 +551,14 @@ def reference(dep: Deployment, reqs: list, idx: List[int],
     from . import reference as ref
 
     c, cfg = dep.corpus, dep.config
-    table = ref.hash_table(dep.table_seed, c.vocab_size, cfg["embed_dim"])
+    prov = provider(cfg["provider"])
+
+    def embed(weights, tokens, segs, precision):
+        return prov.reference(weights, tokens, segs, cfg, precision)
+
     return ref.score_requests(
         [(reqs[i].terms, reqs[i].docs) for i in idx], c.tokens, c.segs,
-        table, jnp.asarray(c.idf), dep.ip, dep.params,
+        embed, dep.provider_weights, jnp.asarray(c.idf), dep.ip, dep.params,
         ranker(cfg["ranker"]).score, n_b=cfg["n_segments"],
         functions=dep.functions, sigma=cfg["sigma_index"],
         max_uniq=dep.max_uniq, precision=precision)

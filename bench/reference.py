@@ -9,11 +9,12 @@ the doc (tf > sigma) and is among the doc's ``max_uniq`` smallest vocabulary
 slots; every other pair, and every padded query slot, is zero.
 
 It imports nothing of the program and takes nothing the program made: the
-table is the hash provider's definition (a normal draw from the seed over
-sqrt(embed_dim)), and tokens, segments, idf and weights are the
-benchmark's own.  Each function is written in its plain form (segment
-means before projections, distances as sums of squared differences), not
-in the factored forms the builder uses.
+static table and the contextual embeddings come from the configuration's
+provider file (``bench/providers/<provider>.py``, its ``reference``), from
+the benchmark's own weights, and tokens, segments, idf and interaction
+weights are the benchmark's own.  Each function is written in its plain
+form (segment means before projections, distances as sums of squared
+differences), not in the factored forms the builder uses.
 
 Every contraction goes through :func:`mm`, so the control can run the same
 code with each matrix product at ``high`` (three bfloat16 passes, emulated
@@ -29,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
-ALPHA = 0.25   # hash provider: ctx(t) = e(t) + ALPHA * mean of its segment
 
 
 def _bf16_parts(x):
@@ -55,21 +55,19 @@ def mm(spec: str, a, b, precision: str = "highest"):
             + jnp.einsum(spec, al, bh, precision=HIGHEST))
 
 
-def hash_table(seed: int, vocab_size: int, embed_dim: int):
-    """The hash provider's embedding table for ``seed``."""
-    return jax.random.normal(jax.random.key(seed), (vocab_size, embed_dim),
-                             dtype=jnp.float32) / jnp.sqrt(embed_dim)
-
-
 def _unit(x):
     return x / jnp.maximum(jnp.sqrt(jnp.sum(x * x, -1, keepdims=True)),
                            1e-9)
 
 
-def interactions(tokens, segs, query, table, idf, ip, *, n_b: int,
+def interactions(tokens, segs, query, table, ctx, idf, ip, *, n_b: int,
                  functions: Sequence[str], sigma: float, max_uniq: int,
                  precision: str = "highest"):
-    """tokens/segs ``(B, L)``, query ``(Q,)`` -> M ``(B, Q, n_b, n_f)``."""
+    """tokens/segs ``(B, L)``, query ``(Q,)`` -> M ``(B, Q, n_b, n_f)``.
+
+    ``table`` ``(|v|, D)`` is the provider's static table, ``ctx``
+    ``(B, L, D)`` its contextual embeddings of the documents' tokens (zero
+    at padding)."""
     mmp = functools.partial(mm, precision=precision)
     valid = tokens >= 0
     qv = query >= 0
@@ -82,10 +80,6 @@ def interactions(tokens, segs, query, table, idf, ip, *, n_b: int,
              & valid[:, None, :] & qv[None, :, None]).astype(jnp.float32)
     tf = mmp("bql,bls->bqs", match, S)                      # (B, Q, n_b)
     cnt = S.sum(1)                                          # (B, n_b)
-
-    # contextual embeddings: the token plus ALPHA x its segment's mean
-    seg_mean = mmp("bls,bld->bsd", S, E) / jnp.maximum(cnt, 1.0)[..., None]
-    ctx = (E + ALPHA * mmp("bls,bsd->bld", S, seg_mean)) * vf[..., None]
     in_seg = S > 0                                          # (B, L, n_b)
 
     def seg_max(x):       # x (B, Q, L) -> max over each segment's tokens
@@ -154,10 +148,12 @@ def doc_meta(tokens, segs, n_b: int):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "n_b", "functions", "sigma", "max_uniq", "precision", "score_fn"))
-def _block(tokens, segs, query, table, idf, ip, params, *, n_b, functions,
-           sigma, max_uniq, precision, score_fn):
-    m = interactions(tokens, segs, query, table, idf, ip, n_b=n_b,
+    "n_b", "functions", "sigma", "max_uniq", "precision", "score_fn",
+    "embed"))
+def _block(tokens, segs, query, weights, idf, ip, params, *, n_b, functions,
+           sigma, max_uniq, precision, score_fn, embed):
+    table, ctx = embed(weights, tokens, segs, precision)
+    m = interactions(tokens, segs, query, table, ctx, idf, ip, n_b=n_b,
                      functions=functions, sigma=sigma, max_uniq=max_uniq,
                      precision=precision)
     doc_len, seg_len = doc_meta(tokens, segs, n_b)
@@ -166,12 +162,16 @@ def _block(tokens, segs, query, table, idf, ip, params, *, n_b, functions,
     return m, s
 
 
-def score_requests(requests, tokens, segs, table, idf, ip, params,
+def score_requests(requests, tokens, segs, embed, weights, idf, ip, params,
                    score_fn, *, n_b, functions, sigma, max_uniq,
                    precision="highest", block=64):
     """Reference ``(M, scores)`` per ``(query, doc_ids)`` request, computed
-    in blocks of ``block`` docs padded to one shape.  Host arrays out."""
+    in blocks of ``block`` docs padded to one shape.  Host arrays out.
+
+    ``embed(weights, tokens, segs, precision) -> (table, ctx)`` is the
+    provider's reference, ``weights`` the provider's arrays."""
     functions = tuple(functions)
+    weights = jax.device_put(weights)
     out = []
     for q, docs in requests:
         ms, ss = [], []
@@ -180,10 +180,10 @@ def score_requests(requests, tokens, segs, table, idf, ip, params,
             n = len(d)
             d = np.concatenate([d, np.full(block - n, d[0], d.dtype)])
             m, sc = _block(jnp.asarray(tokens[d]), jnp.asarray(segs[d]),
-                           jnp.asarray(q), table, idf, ip, params, n_b=n_b,
-                           functions=functions, sigma=float(sigma),
+                           jnp.asarray(q), weights, idf, ip, params,
+                           n_b=n_b, functions=functions, sigma=float(sigma),
                            max_uniq=int(max_uniq), precision=precision,
-                           score_fn=score_fn)
+                           score_fn=score_fn, embed=embed)
             ms.append(np.asarray(m)[:n])
             ss.append(np.asarray(sc)[:n])
         out.append((np.concatenate(ms), np.concatenate(ss)))

@@ -4,13 +4,15 @@
     python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Set-up builds the cell's deployment from ``--seed`` (corpus, weights, the
-index through ``IndexBuilder.build_partitioned``, ``SeineEngine``) and
-warms every shape the cell's traffic uses.  The window then offers the
-mix's open-loop load to ``ServingFrontend.submit`` for ``--seconds``; every
-answer is waited for until a minute past its close.  Afterwards the lookup
-probe runs on a sample of the window's requests, the index is freed, and
-the plain reference checks the sample: the scores the client received and
-the probe's M.
+configuration's provider, and its engine: the index through
+``IndexBuilder.build_partitioned`` and ``SeineEngine``, or ``NoIndexEngine``
+over the documents' tokens) and warms every shape the cell's traffic uses.
+The window then offers the mix's open-loop load to
+``ServingFrontend.submit`` for ``--seconds``; every answer is waited for
+until a minute past its close.  Afterwards the probe computes M of a
+sample of the window's requests as the engine does, the engine is freed,
+and the plain reference checks the sample: the scores the client received
+and the probe's M.
 
 ``--trace 0`` prints the cell's end-to-end metrics, ``--trace 1`` (a
 window under the profiler's device tracer) its per-layer metrics with the

@@ -50,6 +50,19 @@ def test_configs(bench):
         assert any(w["config"] == c["name"] for w in bench["workloads"])
 
 
+def test_every_config_names_its_provider_and_engine(bench):
+    """Each configuration's provider has its file under
+    ``bench/providers/`` (weights, program, reference), and its engine,
+    where it names one, is one the harness serves."""
+    for c in bench["configs"]:
+        with open(os.path.join(H.ROOT, c["file"])) as f:
+            cfg = json.load(f)
+        prov = H.provider(cfg["provider"])
+        for part in ("weights", "program", "reference"):
+            assert callable(getattr(prov, part)), (cfg["provider"], part)
+        assert cfg.get("engine", "seine") in H.ENGINES
+
+
 def test_workloads(bench):
     configs = {c["name"] for c in bench["configs"]}
     pairs = [(w["config"], w["traffic"]) for w in bench["workloads"]]
