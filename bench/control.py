@@ -13,7 +13,9 @@ twice:
   at ``highest`` (what a run compares: the lower reading);
 * the control: the same reference computed at ``high``, three bfloat16
   passes, the precision below the configuration's float32 at ``highest``,
-  put in the program's place (the upper reading; it has to fail a limit).
+  with the provider's part at its file's ``CONTROL_PRECISION`` where it
+  names one (``harness.control``), put in the program's place (the upper
+  reading; it has to fail a limit).
 
 One JSON line per seed on standard output (and in ``--out``).  The
 benchmark's own runs never run the control.
@@ -48,7 +50,7 @@ def readings(H, traffic, cell, seed: int, seconds: float) -> dict:
     got_m = H.probe(dep, mix, reqs, idx)
     dep.release()
     want = H.reference(dep, reqs, idx, "highest")
-    ctrl = H.reference(dep, reqs, idx, "high")
+    ctrl = H.control(dep, reqs, idx)
     checks = H.compare(win, reqs, idx, got_m, want, mix["checks"])
     program = H.gaps([win.scores[i] for i in idx], got_m, want)
     control = H.gaps([c[1] for c in ctrl], [c[0] for c in ctrl], want)

@@ -13,6 +13,13 @@ file of its own, found by the name ``BENCHMARK.json`` gives it:
     bench/traffic/<traffic>.json    a mix, read by ``bench/traffic.py``
     bench/metrics/<metric>.py       one per-layer reader: ``read(run)``
 
+A provider file may also name ``CONTROL_PRECISION``, the precision below
+the one its encoder states, at which the control computes its part
+(:func:`control`), and ``flops(config, n_tokens)``, the operations it
+needs to encode one document (``bench/roofline.py:encoder_flops``).
+A configuration whose ``reduced`` is not empty states its cut in its file
+(:func:`check_cut`).
+
 A configuration's ``"engine"`` (default ``"seine"``) chooses what serves:
 ``"seine"`` builds the index and serves ``SeineEngine``; ``"noindex"``
 builds no index and serves ``NoIndexEngine``, which computes M per request
@@ -94,6 +101,7 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     w = by_name[name]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
     config = _load_json(os.path.join(root, conf["file"]))
+    check_cut(conf, config)
     check_seams(config, root)
     mix = _load_json(os.path.join(root, "bench", "traffic",
                                   w["traffic"] + ".json"))
@@ -101,6 +109,33 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     names = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"] if _for_cell(m, name, names)]
     return Cell(name, int(w["chips"]), config, mix, e2e, per_layer)
+
+
+def check_cut(conf: dict, config: dict) -> None:
+    """Refuse a configuration whose cut is not stated key by key, before
+    any device work.  ``conf`` is its entry in ``BENCHMARK.json``; each key
+    its ``reduced`` lists is a key of the configuration's file, which gives
+    the source's value for it under ``published``, a value that differs
+    from the file's, and under ``chips_per_layer`` over how many chips each
+    layer of the stated deployment is divided (the chip holds one share of
+    a layer; the layers left out lie on further chips).  An empty
+    ``reduced`` needs neither key."""
+    name = conf["name"]
+    published = config.get("published", {})
+    for k in conf["reduced"]:
+        if k not in config:
+            raise ValueError(f"configuration {name!r}: reduced key {k!r} "
+                             f"is not a key of its file")
+        if k not in published:
+            raise ValueError(f"configuration {name!r}: reduced key {k!r} "
+                             f"has no published value")
+        if published[k] == config[k]:
+            raise ValueError(f"configuration {name!r}: reduced key {k!r} "
+                             f"holds its published value {config[k]!r}")
+    cpl = config.get("chips_per_layer")
+    if conf["reduced"] and (type(cpl) is not int or cpl < 1):
+        raise ValueError(f"configuration {name!r}: a cut needs "
+                         f"chips_per_layer, an integer >= 1 (has {cpl!r})")
 
 
 def check_seams(config: dict, root: str = ROOT) -> None:
@@ -543,18 +578,30 @@ def probe(dep: Deployment, mix: dict, reqs: list, idx: List[int]) -> list:
     return [np.asarray(m)[:len(reqs[i].docs)] for m, i in zip(outs, idx)]
 
 
+def doc_tokens(corpus, reqs: list, idx: List[int]) -> list:
+    """Per checked request, the real token count of each of its real
+    candidates (``RunView.probe_doc_tokens``): the work an encoder has to
+    do for it, without the candidates the frontend pads a request with or
+    the positions a document is padded with."""
+    return [(corpus.tokens[reqs[i].docs] >= 0).sum(1) for i in idx]
+
+
 def reference(dep: Deployment, reqs: list, idx: List[int],
-              precision: str = "highest") -> list:
-    """The plain reference's ``(M, scores)`` for the checked requests."""
+              precision: str = "highest",
+              provider_precision: str = None) -> list:
+    """The plain reference's ``(M, scores)`` for the checked requests: the
+    interactions and the ranker at ``precision``, the provider's part at
+    ``provider_precision`` (by default the same)."""
     import jax.numpy as jnp
 
     from . import reference as ref
 
     c, cfg = dep.corpus, dep.config
     prov = provider(cfg["provider"])
+    prov_precision = provider_precision or precision
 
-    def embed(weights, tokens, segs, precision):
-        return prov.reference(weights, tokens, segs, cfg, precision)
+    def embed(weights, tokens, segs, _precision):
+        return prov.reference(weights, tokens, segs, cfg, prov_precision)
 
     return ref.score_requests(
         [(reqs[i].terms, reqs[i].docs) for i in idx], c.tokens, c.segs,
@@ -562,6 +609,17 @@ def reference(dep: Deployment, reqs: list, idx: List[int],
         ranker(cfg["ranker"]).score, n_b=cfg["n_segments"],
         functions=dep.functions, sigma=cfg["sigma_index"],
         max_uniq=dep.max_uniq, precision=precision)
+
+
+def control(dep: Deployment, reqs: list, idx: List[int]) -> list:
+    """The control's ``(M, scores)``: the reference with the interactions
+    and the ranker at ``high`` (three bfloat16 passes, the precision below
+    the configurations' float32 at ``highest``), and the provider's part at
+    the precision below the one the provider states: its file's
+    ``CONTROL_PRECISION``, or ``high`` where it names none."""
+    prov = provider(dep.config["provider"])
+    return reference(dep, reqs, idx, "high",
+                     getattr(prov, "CONTROL_PRECISION", "high"))
 
 
 def gaps(served: list, got_m: list, want: list) -> Dict[str, float]:
@@ -643,7 +701,8 @@ def is_correct(checks: dict) -> bool:
 @dataclasses.dataclass
 class RunView:
     """What a metric's reader reads: the window, set-up and memory, the
-    reduced trace, the probe's requests and the chip's peaks."""
+    reduced trace, the probe's requests with the real tokens of each of
+    their candidates, and the chip's peaks."""
     window: Window
     mix: dict
     config: dict
@@ -652,6 +711,7 @@ class RunView:
     trace: object = None                 # bench.trace.Trace
     trace_window: tuple = None           # (start_ns, end_ns)
     probe_requests: list = None          # [(terms, n_candidates)]
+    probe_doc_tokens: list = None        # [(n_candidates,) real tokens]
     peaks: dict = None
 
 

@@ -18,7 +18,8 @@ differences), not in the factored forms the builder uses.
 
 Every contraction goes through :func:`mm`, so the control can run the same
 code with each matrix product at ``high`` (three bfloat16 passes, emulated
-so that it means the same on any backend) instead of ``highest``.
+so that it means the same on any backend) instead of ``highest``, and a
+provider that states bfloat16 can run its part at :data:`BELOW_BF16`.
 """
 from __future__ import annotations
 
@@ -30,6 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
+# the one precision below a bfloat16 model: float8 e4m3 operands
+BELOW_BF16 = "fp8_e4m3"
 
 
 def _bf16_parts(x):
@@ -41,11 +44,26 @@ def _bf16_parts(x):
                                         mantissa_bits=7)
 
 
+def _e4m3(x):
+    """x rounded to float8 e4m3 (4 exponent, 3 mantissa bits), by IEEE
+    rules: the largest finite value is 240, and beyond it lies inf."""
+    return jax.lax.reduce_precision(x, exponent_bits=4, mantissa_bits=3)
+
+
 def mm(spec: str, a, b, precision: str = "highest"):
-    """``einsum(spec, a, b)`` in float32 at ``highest``, or at ``high``:
-    the bfloat16 three-pass product ``hi*hi + hi*lo + lo*hi``."""
+    """``einsum(spec, a, b)`` in float32 at ``highest``; at ``high``, the
+    bfloat16 three-pass product ``hi*hi + hi*lo + lo*hi``; or at
+    :data:`BELOW_BF16` (``"fp8_e4m3"``), the product at ``highest`` of
+    operands rounded to float8 e4m3.
+
+    ``fp8_e4m3`` is the control of a model that states bfloat16 weights and
+    activations with float32 accumulation: it has to lie below that
+    precision.  Rounding each product's result to bfloat16 would not: a
+    bfloat16 deployment already rounds its results so."""
     if precision == "highest":
         return jnp.einsum(spec, a, b, precision=HIGHEST)
+    if precision == BELOW_BF16:
+        return jnp.einsum(spec, _e4m3(a), _e4m3(b), precision=HIGHEST)
     if precision != "high":
         raise ValueError(f"unknown precision {precision!r}")
     ah, al = _bf16_parts(a)

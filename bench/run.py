@@ -123,6 +123,16 @@ def main(argv=None) -> int:
     dep.release()
     want = H.reference(dep, reqs, idx) if idx else []
     log("check", requests=len(idx), s=time.perf_counter() - t)
+    doc_tokens = H.doc_tokens(dep.corpus, reqs, idx)
+    if idx:
+        # what an encoder would encode of the checked requests, against
+        # the candidates and positions the frontend and the corpus pad to
+        real = sum(int(n.sum()) for n in doc_tokens)
+        slots = sum(len(H.padded(reqs[i].docs, mix["batch_pad"]))
+                    for i in idx) * dep.corpus.tokens.shape[1]
+        log("work", doc_tokens_per_request=real / len(idx),
+            padded_slots_per_request=slots / len(idx),
+            real_share=real / slots)
     checks = H.compare(win, reqs, idx, got_m, want, mix["checks"]) \
         if idx else {"lost": {"value": len(reqs), "limit": 0}}
     if idx:
@@ -135,6 +145,7 @@ def main(argv=None) -> int:
                      setup_s=setup_s, memory_peak_bytes=peak,
                      probe_requests=[(reqs[i].terms, len(reqs[i].docs))
                                      for i in idx],
+                     probe_doc_tokens=doc_tokens,
                      peaks=H.peaks(device["kind"]))
     breakdown = None
     if tdir:

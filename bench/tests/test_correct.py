@@ -12,6 +12,7 @@ read: only the comparison of the probe's M can see it).  The control is
 the reference at ``high`` put in the program's place.
 """
 import json
+import types
 
 import jax.numpy as jnp
 import numpy as np
@@ -76,17 +77,46 @@ def test_sound_run_is_correct(monkeypatch, world, capsys):
 
 
 def test_control_fails(world):
-    """The reference at ``high`` in the program's place reads over a limit
-    of the served scores and over that of the probe's M."""
+    """The control (the reference at ``high``: the hash provider names no
+    ``CONTROL_PRECISION``) in the program's place reads over a limit of
+    the served scores and over that of the probe's M."""
     cell, dep = world
     reqs = traffic.schedule(cell.mix, dep.corpus, 3, 0.5)
     idx = list(range(len(reqs)))
     want = H.reference(dep, reqs, idx, "highest")
-    ctrl = H.reference(dep, reqs, idx, "high")
+    ctrl = H.control(dep, reqs, idx)
+    for (m0, s0), (m1, s1) in zip(H.reference(dep, reqs, idx, "high"),
+                                  ctrl):
+        assert np.array_equal(m0, m1) and np.array_equal(s0, s1)
     got = H.gaps([c[1] for c in ctrl], [c[0] for c in ctrl], want)
     lim = cell.mix["checks"]
     assert got["score_rms"] > lim["score_rms"]
     assert got["m_rms"] > lim["m_rms"]
+
+
+def test_control_takes_the_providers_precision(monkeypatch, world):
+    """A provider's ``CONTROL_PRECISION`` reaches its ``reference`` in the
+    control; the interactions and the ranker stay at ``high``."""
+    from bench import reference as ref
+
+    cell, dep = world
+    hp = H.provider("hash")
+    seen = []
+
+    def reference(weights, tokens, segs, config, precision="highest"):
+        seen.append(precision)
+        return hp.reference(weights, tokens, segs, config, precision)
+
+    toy = types.SimpleNamespace(CONTROL_PRECISION=ref.BELOW_BF16,
+                                reference=reference)
+    monkeypatch.setattr(H, "provider", lambda name: toy)
+    reqs = traffic.schedule(cell.mix, dep.corpus, 5, 0.2)[:2]
+    ctrl = H.control(dep, reqs, [0, 1])
+    assert seen and set(seen) == {ref.BELOW_BF16}
+    both = H.reference(dep, reqs, [0, 1], "high", ref.BELOW_BF16)
+    assert all(np.array_equal(a[1], b[1]) for a, b in zip(ctrl, both))
+    high = H.reference(dep, reqs, [0, 1], "high")
+    assert not all(np.array_equal(a[0], b[0]) for a, b in zip(ctrl, high))
 
 
 def _patch_score(monkeypatch, fn):

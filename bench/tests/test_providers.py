@@ -123,11 +123,11 @@ def world(request):
     return cfg, seed, c, ip, params
 
 
-@pytest.mark.parametrize("precision", ["highest", "high"])
+@pytest.mark.parametrize("precision", ["highest", "high", ref.BELOW_BF16])
 def test_hash_reference_is_bitwise_the_inline_one(world, precision):
     """M and scores through ``bench/providers/hash.py`` equal the frozen
-    inline computation bit for bit, at ``highest`` and at ``high`` (the
-    control's precision)."""
+    inline computation bit for bit, at ``highest``, at ``high`` (the
+    control's precision) and below bfloat16."""
     cfg, seed, c, ip, params = world
     hp = H.provider("hash")
     w = hp.weights(cfg, seed, c.vocab_size)
@@ -176,6 +176,32 @@ def test_hash_table_is_the_programs(world):
                               jnp.asarray(c.segs[:1]), cfg)
     np.testing.assert_allclose(np.asarray(ctx_ref[0]),
                                np.asarray(ctx_prog), rtol=1e-5, atol=1e-6)
+
+
+def test_mm_below_bf16_is_highest_on_e4m3_operands():
+    """``mm`` at ``BELOW_BF16`` is the ``highest`` product of operands
+    rounded to float8 e4m3, and lies further from ``highest`` than one
+    bfloat16 pass does (operands rounded to bfloat16): so it is below a
+    model that states bfloat16."""
+    k = jax.random.split(jax.random.key(2**31 + 7), 2)
+    a = jax.random.normal(k[0], (64, 256), jnp.float32)
+    b = jax.random.normal(k[1], (256, 32), jnp.float32)
+    got = ref.mm("ij,jk->ik", a, b, ref.BELOW_BF16)
+
+    def rounded(x, e, m):
+        return jax.lax.reduce_precision(x, exponent_bits=e, mantissa_bits=m)
+
+    want = jnp.einsum("ij,jk->ik", rounded(a, 4, 3), rounded(b, 4, 3),
+                      precision=jax.lax.Precision.HIGHEST)
+    assert np.array_equal(_bits(got), _bits(want))
+    exact = ref.mm("ij,jk->ik", a, b)
+    one_pass = jnp.einsum("ij,jk->ik", rounded(a, 8, 7), rounded(b, 8, 7),
+                          precision=jax.lax.Precision.HIGHEST)
+    gap_fp8 = float(jnp.sqrt(jnp.mean((got - exact) ** 2)))
+    gap_bf16 = float(jnp.sqrt(jnp.mean((one_pass - exact) ** 2)))
+    assert gap_fp8 > 4 * gap_bf16 > 0
+    with pytest.raises(ValueError):
+        ref.mm("ij,jk->ik", a, b, "fp8")
 
 
 def test_provider_references_import_nothing_of_the_program():

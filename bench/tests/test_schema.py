@@ -35,19 +35,83 @@ def test_top_level(bench):
     assert len(json.dumps(bench)) <= 64 * 1024
 
 
-def test_configs(bench):
+def _check_configs(bench, root):
     names = [c["name"] for c in bench["configs"]]
     assert len(set(names)) == len(names)
     for c in bench["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert NAME.match(c["name"]) and c["file"].startswith("bench/")
-        with open(os.path.join(H.ROOT, c["file"])) as f:
+        with open(os.path.join(root, c["file"])) as f:
             cfg = json.load(f)
         assert cfg["name"] == c["name"] and cfg["source"] == c["source"]
-        assert c["reduced"] == []
+        # a cut is stated key by key: each reduced key is in the file, with
+        # a published value that differs, and the chips that share a layer
+        assert len(c["reduced"]) <= 16
+        assert all(NAME.match(k) for k in c["reduced"])
+        H.check_cut(c, cfg)
         assert os.path.isfile(os.path.join(H.BENCH, "rankers",
                                            cfg["ranker"] + ".py"))
         assert any(w["config"] == c["name"] for w in bench["workloads"])
+
+
+def test_configs(bench):
+    _check_configs(bench, H.ROOT)
+
+
+# a one-chip share of Moonlight-16B-A3B's layers, as a later configuration
+# would state it: 1 dense + 4 MoE layers, 8 of 64 experts, 1/8 of the rows
+CUT = {"n_layers": (5, 27), "n_experts": (8, 64),
+       "vocab_size": (20480, 163840)}
+
+
+def _cut_checkout(tmp_path, bench, fault=None):
+    """A checkout whose ``BENCHMARK.json`` adds a configuration that lists
+    the keys of :data:`CUT` in ``reduced``, and a cell of it; ``fault``
+    leaves one part of the stated cut out."""
+    root = tmp_path / "checkout"
+    shutil.copytree(H.BENCH, root / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    base = bench["configs"][1]
+    with open(os.path.join(H.ROOT, base["file"])) as f:
+        cfg = json.load(f)
+    cfg = dict(cfg, name="cut", chips_per_layer=8,
+               published={k: p for k, (_, p) in CUT.items()},
+               **{k: v for k, (v, _) in CUT.items()})
+    if fault == "key_not_in_file":
+        del cfg["n_experts"]
+    elif fault == "published_equals_file":
+        cfg["published"] = dict(cfg["published"], vocab_size=20480)
+    elif fault == "no_chips_per_layer":
+        del cfg["chips_per_layer"]
+    (root / "bench" / "configs" / "cut.json").write_text(json.dumps(cfg))
+    new = dict(bench)
+    new["configs"] = bench["configs"] + [dict(
+        base, name="cut", file="bench/configs/cut.json",
+        reduced=list(CUT))]
+    new["workloads"] = bench["workloads"] + [
+        {"name": "cut.letor", "config": "cut", "traffic": "letor",
+         "chips": 1, "why": "a later cell of a cut configuration"}]
+    (root / "BENCHMARK.json").write_text(json.dumps(new))
+    return new, str(root)
+
+
+def test_configs_accept_a_stated_cut(tmp_path, bench):
+    new, root = _cut_checkout(tmp_path, bench)
+    _check_configs(new, root)
+    cell = H.load_cell("cut.letor", root=root)
+    assert cell.config["chips_per_layer"] == 8
+    assert cell.config["published"]["n_experts"] == 64
+
+
+@pytest.mark.parametrize("fault", ["key_not_in_file",
+                                   "published_equals_file",
+                                   "no_chips_per_layer"])
+def test_configs_refuse_an_unstated_cut(tmp_path, bench, fault):
+    new, root = _cut_checkout(tmp_path, bench, fault)
+    with pytest.raises(ValueError):
+        _check_configs(new, root)
+    with pytest.raises(ValueError):
+        H.load_cell("cut.letor", root=root)
 
 
 def test_every_config_names_its_provider_and_engine(bench):
@@ -161,6 +225,43 @@ def test_lookup_bytes_count_real_pairs_only():
         == 3 * 1000 * 180 * 8
     assert roofline.lookup_bytes([(q, 1000), (q[:0], 64)], 20, 9,
                                  "float32") == 3 * 1000 * 180 * 8
+
+
+def test_doc_tokens_count_real_tokens_of_real_candidates():
+    """``probe_doc_tokens``: per checked request, the real tokens of each
+    candidate it sent, not the candidates the frontend pads it with
+    (``batch_pad``) nor the positions each document is padded with."""
+    from bench.traffic import Request
+
+    tokens = np.full((6, 16), -1, np.int32)
+    for d, n in enumerate([3, 16, 0, 7, 1, 12]):
+        tokens[d, :n] = np.arange(n) + 2
+    corpus = type("Corpus", (), {"tokens": tokens})
+    q = np.array([2, 3, -1, -1], np.int32)
+    reqs = [Request(0.0, 0, q, np.array([1, 3, 4], np.int32)),
+            Request(0.1, 1, q, np.array([5], np.int32))]
+    assert len(H.padded(reqs[0].docs, 8)) == 8
+    got = H.doc_tokens(corpus, reqs, [1, 0])
+    assert [n.tolist() for n in got] == [[12], [16, 7, 1]]
+
+
+def test_encoder_flops_count_real_documents_one_by_one():
+    """A provider's ``flops`` summed per real document, at its own length
+    (a length-squared term tells one document of 2n tokens from two of n);
+    ``None`` for ``hash``, which names no ``flops``."""
+    def flops(config, n):
+        return config["per_token"] * n + n * n
+
+    toy = type("Toy", (), {"flops": staticmethod(flops)})
+    cfg = {"per_token": 10}
+    assert roofline.encoder_flops(toy, cfg, [np.array([3, 5]),
+                                             np.array([2])]) \
+        == (30 + 9) + (50 + 25) + (20 + 4)
+    assert roofline.encoder_flops(toy, cfg, [np.array([8])]) \
+        != roofline.encoder_flops(toy, cfg, [np.array([4, 4])])
+    assert roofline.encoder_flops(toy, cfg, []) == 0
+    assert roofline.encoder_flops(H.provider("hash"), cfg,
+                                  [np.array([3])]) is None
 
 
 def test_peaks_table():
